@@ -143,7 +143,7 @@ def overlap_audit(rows: int = 512, d: int = 32) -> list[dict]:
     dot FLOPs from the optimized HLO (``launch.hlo_cost``) and reports the
     roofline ``overlap_fraction``: the share of collective time the
     latency-hiding scheduler may hide behind compute (async -start/-done
-    pairs, capped by available compute).  Needs >= 4 devices (real or
+    pairs, capped by available compute) on a TPU.  Needs >= 4 devices (real or
     ``--xla_force_host_platform_device_count`` fakes); on a single-device
     run it emits a skip marker — the modeled audit in
     ``bench_serve.ep_overlap_audit`` is then the signal.
@@ -163,8 +163,13 @@ def overlap_audit(rows: int = 512, d: int = 32) -> list[dict]:
     from repro.compat import shard_map
     from repro.core import exchange
     from repro.launch import hlo_cost
+    from repro.core.topology import chip_spec
     from repro.launch.roofline import RooflineTerms
 
+    # Device time is priced for the chip that compiled the HLO; fake CPU
+    # devices have no ChipSpec, so there the audit records bytes only.
+    dev = jax.devices()[0]
+    chip = chip_spec(dev.device_kind) if dev.platform == "tpu" else None
     records = []
     for pods in (1, 2):
         mesh = Mesh(
@@ -201,29 +206,32 @@ def overlap_audit(rows: int = 512, d: int = 32) -> list[dict]:
             .lower(x, w).compile()
         )
         cost = hlo_cost.analyze(compiled.as_text())
-        terms = RooflineTerms(
-            arch="two_level_ep", shape=f"{rows}x{d}",
-            mesh=f"{pods}x{n // pods}",
-            flops_per_chip=cost["flops"], bytes_per_chip=cost["bytes"],
-            coll_bytes_per_chip=cost["collective_bytes"],
-            model_flops_global=0.0, chips=n,
-            async_coll_bytes_per_chip=cost["async_collective_bytes"],
-        )
+        mesh_name = f"{pods}x{n // pods}"
+        frac = None
+        if chip is not None:
+            frac = RooflineTerms(
+                arch="two_level_ep", shape=f"{rows}x{d}", mesh=mesh_name,
+                flops_per_chip=cost["flops"], bytes_per_chip=cost["bytes"],
+                coll_bytes_per_chip=cost["collective_bytes"],
+                model_flops_global=0.0, chips=n, chip=chip,
+                async_coll_bytes_per_chip=cost["async_collective_bytes"],
+            ).overlap_fraction
         coll_total = sum(cost["collective_bytes"].values())
         coll_async = sum(cost["async_collective_bytes"].values())
-        emit(f"overlap_audit/{terms.mesh}/collective_bytes", coll_total, "B",
+        emit(f"overlap_audit/{mesh_name}/collective_bytes", coll_total, "B",
              ",".join(sorted(cost["collective_bytes"])))
-        emit(f"overlap_audit/{terms.mesh}/async_bytes", coll_async, "B",
+        emit(f"overlap_audit/{mesh_name}/async_bytes", coll_async, "B",
              "-start/-done pairs the scheduler may overlap")
-        emit(f"overlap_audit/{terms.mesh}/overlap_fraction",
-             f"{terms.overlap_fraction:.3f}", "",
-             "HLO-derived; 0 when the backend lowers collectives sync")
+        emit(f"overlap_audit/{mesh_name}/overlap_fraction",
+             "not measured" if frac is None else f"{frac:.3f}", "",
+             f"HLO-derived, priced for {dev.device_kind}" if chip else
+             f"no chip spec for {dev.platform}: bytes only")
         records.append({
-            "mesh": terms.mesh,
+            "mesh": mesh_name,
             "collective_bytes": cost["collective_bytes"],
             "async_collective_bytes": cost["async_collective_bytes"],
             "flops_per_chip": cost["flops"],
-            "overlap_fraction": round(terms.overlap_fraction, 4),
+            "overlap_fraction": None if frac is None else round(frac, 4),
         })
     return records
 

@@ -11,10 +11,10 @@ planner's modeled exchange profile instead: shuffle/broadcast edge counts
 and wire bytes per query at 8 shards (the paper's "data shuffled" row),
 straight from the physical plan that the golden snapshots pin down.
 
-The modeled numbers are checked against measurement: the bench shells out
-to ``repro.obs.model_check`` (a traced run on an 8-fake-device mesh — the
-XLA device-count flag must precede jax init, hence a subprocess) and
-records each edge's ``byte_model_err`` — measured wire bytes from the
+The modeled numbers are checked against measurement: the bench runs
+``repro.obs.model_check`` (a traced run — on a TPU in this process over the
+chips present, elsewhere in a subprocess on an 8-fake-device mesh, since
+the XLA device-count flag must precede jax init) and records each edge's ``byte_model_err`` — measured wire bytes from the
 in-jit destination histograms vs the planner's estimate-priced model.
 ``--compare`` gates those leaves lower-is-better at the usual 2x.
 
@@ -39,12 +39,34 @@ MODEL_CHECKS = (("q17", True), ("q3", True))
 SMOKE_MODEL_CHECKS = (("q17", True),)
 
 
+def _check_shards() -> int:
+    """Mesh size of the model check: the chips present on a TPU, else
+    ``PLAN_SHARDS`` fake CPU devices."""
+    return jax.device_count() if jax.default_backend() == "tpu" else PLAN_SHARDS
+
+
 def _model_check(query: str, streamed: bool, trace_dir: str | None) -> dict:
-    """One traced query under ``repro.obs.model_check`` on fake devices."""
+    """One traced query under ``repro.obs.model_check``.
+
+    On a TPU it runs in this process on the chips present (a chip belongs
+    to one process, so a child could not take it); elsewhere in a child on
+    ``PLAN_SHARDS`` fake CPU devices.
+    """
     import json
     import os
     import subprocess
     import sys
+
+    if jax.default_backend() == "tpu":
+        import argparse
+
+        from repro.obs import model_check
+
+        return model_check._cli_run(argparse.Namespace(
+            query=query, sf=0.01, shards=_check_shards(), pods=1,
+            streamed=streamed, morsel_rows=0, trace_dir=trace_dir,
+            bound=model_check.BYTE_MODEL_BOUND,
+        ))
 
     cmd = [sys.executable, "-m", "repro.obs.model_check",
            "--query", query, "--shards", str(PLAN_SHARDS)]
@@ -102,45 +124,6 @@ def _handwritten_runners(tabs):
     }
 
 
-def _correct(name, got, tabs) -> bool:
-    li, part = tabs["lineitem"], tabs["part"]
-    cust, orders = tabs["customer"], tabs["orders"]
-    if name == "q1":
-        want = oracle.q1_oracle(li)
-        return all(
-            np.allclose(np.asarray(got[k]), want[k], rtol=1e-4) for k in want
-        )
-    if name == "q6":
-        return np.allclose(float(got), oracle.q6_oracle(li), rtol=1e-4)
-    if name == "q17":
-        return np.allclose(float(got), oracle.q17_oracle(li, part), rtol=1e-3)
-    if name == "q3":
-        want = oracle.q3_oracle(cust, orders, li)
-        return [int(k) for k in got["o_orderkey"]] == \
-            [int(k) for k in want["o_orderkey"]]
-    if name == "q14":
-        return np.allclose(float(got), oracle.q14_oracle(li, part), rtol=1e-3)
-    if name == "q19":
-        return np.allclose(float(got), oracle.q19_oracle(li, part), rtol=1e-3)
-    if name == "q4":
-        return np.allclose(
-            np.asarray(got["order_count"]), oracle.q4_oracle(li, orders)
-        )
-    if name == "q12":
-        want = oracle.q12_oracle(li, orders)
-        return np.allclose(
-            got["high_line_count"], want["high_line_count"]
-        ) and np.allclose(got["low_line_count"], want["low_line_count"])
-    if name == "q18":
-        want = oracle.q18_oracle(li, orders, cust)
-        gm = dict(zip(got["o_orderkey"].tolist(),
-                      got["o_totalprice"].tolist()))
-        wm = dict(zip(want["o_orderkey"].tolist(),
-                      want["o_totalprice"].tolist()))
-        return gm == wm
-    raise KeyError(name)
-
-
 def run(smoke: bool = False, trace_dir: str | None = None):
     sf = 0.01 if smoke else SF
     iters = 3 if smoke else 5
@@ -167,7 +150,7 @@ def run(smoke: bool = False, trace_dir: str | None = None):
         t_planned = time_jit(runner, iters=iters)
         raw = runner()
         got = pq.finalize(raw) if pq.finalize else raw
-        ok = _correct(name, got, tabs)
+        ok = oracle.matches(name, got, oracle.expected(name, tabs))
 
         t_hand = None
         if name in hand:
@@ -203,7 +186,7 @@ def run(smoke: bool = False, trace_dir: str | None = None):
         }
         emit(f"tpch/{qname}_byte_model_err",
              f"{worst:.3f}" if worst is not None else "n/a", "x",
-             f"measured vs modeled wire bytes @ {PLAN_SHARDS} fake devices"
+             f"measured vs modeled wire bytes @ {_check_shards()} devices"
              + (" (streamed)" if streamed else ""))
     return record
 

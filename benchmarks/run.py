@@ -24,7 +24,7 @@ slot-steps are lower-is-better; ``tok_s`` and the ``*_ratio`` /
 ``*_fraction`` scores are higher-is-better; everything else (counts,
 flags, tuned knobs) is informational and not gated.
 The roofline section reads the dry-run artifacts (run
-``python -m repro.launch.dryrun`` first).
+``python -m repro.launch.dryrun --device-kind "TPU v5 lite"`` first).
 """
 
 import argparse
@@ -32,6 +32,8 @@ import glob as _glob
 import json
 import os
 import sys
+
+from repro.launch.compile_cache import enable_compile_cache
 
 from . import (
     bench_autotune,
@@ -214,6 +216,8 @@ def main():
     p.add_argument("--compare-threshold", type=float, default=2.0,
                    help="worsening ratio that counts as a regression")
     args = p.parse_args()
+    if args.smoke or args.compare is None:  # --compare alone compiles nothing
+        enable_compile_cache()
     print("name,value,unit,note")
     if args.smoke:
         smoke(args.json_dir, trace_dir=args.trace_dir)

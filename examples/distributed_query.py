@@ -1,10 +1,12 @@
 """The paper end-to-end: distributed TPC-H over the scheduled exchange.
 
-    python examples/distributed_query.py          # 8 fake devices
+    JAX_PLATFORMS=cpu python examples/distributed_query.py   # 8 fake CPU devices
+    python examples/distributed_query.py                     # every device present
 
 Runs Q1/Q6/Q17/Q3 plus the plan-only Q4/Q12/Q18 through the cost-based
-query planner on an 8-way mesh (the paper's 6-server cluster, rounded up
-to a power of two) and checks every result against the numpy oracle.  Q17
+query planner on a mesh over every device (on the CPU, 8 fake devices: the
+paper's 6-server cluster, rounded up to a power of two) and checks every
+result against the numpy oracle.  Q17
 is the paper's own worked example (Fig 6): the planner broadcasts the
 filtered part side per the hybrid threshold and shares one lineitem
 shuffle — its ``explain()`` is printed first.
@@ -12,9 +14,14 @@ shuffle — its ``explain()`` is printed first.
 
 import os, sys
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    # the flag must precede JAX's initialization; it shapes only the CPU
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+    )
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+import jax
 import numpy as np
 
 from repro.relational import datagen, distributed as D, oracle
@@ -27,7 +34,7 @@ def main():
     tabs = datagen.gen_all(sf)
     li, part = tabs["lineitem"], tabs["part"]
     cust, orders = tabs["customer"], tabs["orders"]
-    n = ExecutionContext(num_shards=8)
+    n = ExecutionContext(num_shards=jax.device_count())
 
     # the cost-based planner's view of Q17 (the paper's Fig 6 example)
     from repro.relational.planner import tpch

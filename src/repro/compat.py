@@ -1,32 +1,28 @@
-"""JAX version-compat shims.
+"""Thin wrappers over the JAX mesh API that every mesh-building module shares.
 
-The codebase targets the current public API (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.sharding.AxisType``) but must
-also run on jax 0.4.x, where ``shard_map`` still lives in
-``jax.experimental.shard_map`` (with ``check_rep`` instead of ``check_vma``)
-and ``make_mesh`` takes no ``axis_types``.  Every module that builds meshes
-or shard_maps goes through these two functions instead of touching ``jax``
-directly.
+``make_mesh`` fixes the axis types to Auto, ``shard_map`` keeps the repo's
+keyword spelling, ``enable_cpu_collectives`` switches on Gloo for the
+multi-process CPU harness, and ``fetch`` reads arrays that span processes.
 """
 
 from __future__ import annotations
 
+import os
 from typing import Any, Sequence
 
 import jax
+import numpy as np
 
 
 def make_mesh(
     axis_shapes: Sequence[int], axis_names: Sequence[str]
 ) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            axis_shapes,
-            axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-        )
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes,
+        axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )
 
 
 def shard_map(
@@ -38,101 +34,46 @@ def shard_map(
     check_vma: bool = True,
     axis_names: Any = None,
 ):
-    """``jax.shard_map`` on new jax, ``jax.experimental.shard_map`` on old.
-
-    ``check_vma`` maps onto the old API's ``check_rep`` (both gate the
-    replication/varying-manual-axes consistency check).  ``axis_names`` (the
-    set of mesh axes the body is manual over) is honored on new jax; on
-    0.4.x the equivalent partial-manual mode (``auto=`` complement) hits an
-    XLA SPMD-partitioner check failure, so we run fully manual instead —
-    axes not mentioned in a spec are then treated as replicated, which is
-    semantically equivalent for bodies that only communicate over their
-    manual axes (all in-repo call sites).
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-            **kwargs,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """``jax.shard_map``; ``axis_names`` (the axes the body is manual over)
+    defaults to every mesh axis."""
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
+        axis_names=frozenset(axis_names or ()),
     )
 
 
-def set_mesh(mesh: jax.sharding.Mesh):
-    """Context manager making ``mesh`` the ambient mesh.
-
-    ``jax.set_mesh`` on new jax; on 0.4.x the ``Mesh`` object itself is the
-    context manager that sets the global mesh for sharding resolution.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def axis_size(axis_name: Any) -> int:
-    """Static size of a named mesh axis from inside shard_map.
-
-    ``jax.lax.axis_size`` on new jax; on 0.4.x the classic idiom —
-    ``psum`` of a unit constant, which the axis environment folds to a
-    concrete int at trace time.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return int(jax.lax.psum(1, axis_name))
-
-
-def enable_cpu_collectives() -> bool:
-    """Turn on cross-process CPU collectives (Gloo) where the jax supports it.
+def enable_cpu_collectives() -> None:
+    """Turn on cross-process CPU collectives (Gloo).
 
     Multi-process CPU runs (``launch/cluster.py``) need a CPU collectives
     backend — without one every cross-process psum/ppermute fails with
-    "Multiprocess computations aren't implemented on the CPU backend".  The
-    config knob is ``jax_cpu_collectives_implementation`` on 0.4.35+; older
-    jaxlibs only honor the environment variable, and some builds ship
-    without Gloo at all — so failure here is reported, not raised (the
-    caller decides whether multi-process was mandatory).  Must run before
-    the CPU backend is initialized (i.e. before any device query).
+    "Multiprocess computations aren't implemented on the CPU backend".
+    Must run before the CPU backend is initialized (i.e. before any device
+    query).
     """
-    import os
-
     os.environ.setdefault("JAX_CPU_COLLECTIVES_IMPLEMENTATION", "gloo")
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, KeyError, ValueError):
-        return False
-    return True
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 
 def fetch(x: Any):
     """Concrete numpy value of an array that may span multiple processes.
 
-    Single-process (every device addressable): plain ``np.asarray``.  In a
-    multi-process run a jit output can span devices this process cannot
-    address, and 0.4.x raises on plain value fetch even for replicated
-    outputs — read the local shard when the array is fully replicated, and
-    all-gather across processes otherwise.  Pytrees are mapped leaf-wise.
+    Addressable or fully replicated arrays read directly; an array sharded
+    over devices this process cannot address is all-gathered across
+    processes.  Pytrees are mapped leaf-wise.
     """
-    import numpy as np
 
     def one(leaf):
-        if not hasattr(leaf, "sharding"):  # numpy / python scalar
+        if (
+            not hasattr(leaf, "sharding")  # numpy / python scalar
+            or leaf.is_fully_addressable
+            or leaf.is_fully_replicated
+        ):
             return np.asarray(leaf)
-        if leaf.is_fully_addressable:
-            return np.asarray(leaf)
-        if leaf.is_fully_replicated:
-            return np.asarray(leaf.addressable_shards[0].data)
         from jax.experimental import multihost_utils
 
         return np.asarray(multihost_utils.process_allgather(leaf, tiled=True))
@@ -140,11 +81,4 @@ def fetch(x: Any):
     return jax.tree.map(one, x)
 
 
-__all__ = [
-    "make_mesh",
-    "shard_map",
-    "set_mesh",
-    "axis_size",
-    "enable_cpu_collectives",
-    "fetch",
-]
+__all__ = ["make_mesh", "shard_map", "enable_cpu_collectives", "fetch"]

@@ -66,7 +66,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.compat import axis_size as _axis_size
 from .schedule import Schedule, make_schedule
 
 AllToAllImpl = Literal["xla", "round_robin", "one_factorization"]
@@ -83,7 +82,7 @@ def xla_all_to_all(x: jax.Array, axis_name: str) -> jax.Array:
     ``x[j]`` (leading dim = axis size) is the chunk destined for device ``j``;
     the result's ``y[j]`` is the chunk received from device ``j``.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     assert x.shape[0] == n, f"leading dim {x.shape[0]} != axis size {n}"
     return lax.all_to_all(x, axis_name, split_axis=0, concat_axis=0, tiled=True)
 
@@ -122,7 +121,7 @@ def scheduled_all_to_all(
     buffering at the transport level).  Requires ``x.ndim >= 2`` and
     ``x.shape[1] % num_chunks == 0``.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     assert x.shape[0] == n, f"leading dim {x.shape[0]} != axis size {n}"
     if n == 1:
         return x
@@ -200,7 +199,7 @@ def scheduled_all_to_all_consume(
     instead of waiting for the full shuffle.  Avoids materializing the
     ``[n, ...]`` receive buffer (the message pool is one chunk deep).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     assert x.shape[0] == n
     me = lax.axis_index(axis_name)
     own = lax.dynamic_slice_in_dim(x, me, 1, axis=0)
@@ -230,7 +229,7 @@ def ring_all_gather(x: jax.Array, axis_name: str) -> jax.Array:
     remote server" (vs ``n*t - 1`` sends under classic exchange).  Result
     ``y[j]`` is device ``j``'s chunk.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     y = jnp.zeros((n,) + x.shape, x.dtype)
     y = lax.dynamic_update_slice_in_dim(y, x[None], me, axis=0)
@@ -287,7 +286,7 @@ def hierarchical_psum_tree(tree: Any, inner_axis: str, outer_axis: str) -> Any:
     def one(leaf: jax.Array) -> jax.Array:
         flat = leaf.reshape(-1)
         n = flat.shape[0]
-        inner = _axis_size(inner_axis)
+        inner = lax.axis_size(inner_axis)
         padded = _pad_to(flat, inner)
         red = hierarchical_psum(padded, inner_axis, outer_axis)
         return red[:n].reshape(leaf.shape)
@@ -431,7 +430,7 @@ def hash_shuffle(
     (must divide the per-chunk capacity; the tiny counts exchange is never
     split).
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     T = keys.shape[0]
     if valid is None:
         valid = jnp.ones((T,), jnp.bool_)
@@ -515,7 +514,7 @@ def hash_shuffle_spill(
     runs on the sender (paper §3.2 step 2): ``my_rank >= capacity`` is
     exactly the overflow condition the fixed-size message pool would hit.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     T = keys.shape[0]
     if valid is None:
         valid = jnp.ones((T,), jnp.bool_)
@@ -590,7 +589,7 @@ def hash_shuffle_two_level(
     phase sequence and ships unchunked).  The returned ``dropped`` is
     psummed over BOTH axes — a global count.
     """
-    P = _axis_size(outer_axis)
+    P = lax.axis_size(outer_axis)
     if P == 1:
         out_rows, out_valid, dropped = hash_shuffle(
             keys, rows, inner_axis, capacity, impl=impl, valid=valid,
@@ -598,7 +597,7 @@ def hash_shuffle_two_level(
             transport_chunks=transport_chunks,
         )
         return out_rows, out_valid, lax.psum(dropped, outer_axis)
-    n = _axis_size(inner_axis)
+    n = lax.axis_size(inner_axis)
     N = P * n
     T = keys.shape[0]
     if valid is None:
@@ -698,8 +697,8 @@ def dispatch_two_level(
     caller has already assigned every row a destination slot (the leading
     index); the two-level route only changes *how* the bytes move.
     """
-    P = _axis_size(outer_axis)
-    n = _axis_size(inner_axis)
+    P = lax.axis_size(outer_axis)
+    n = lax.axis_size(inner_axis)
     if P == 1:
         return all_to_all(x, inner_axis, impl=impl, num_chunks=num_chunks)
     N = P * n
@@ -730,8 +729,8 @@ def combine_two_level(
     contract), with the hop order mirrored: fine in-pod first, then ONE
     coarse message per peer pod over ``outer_axis``.  Also a pure
     permutation — bit-identical to the flat route."""
-    P = _axis_size(outer_axis)
-    n = _axis_size(inner_axis)
+    P = lax.axis_size(outer_axis)
+    n = lax.axis_size(inner_axis)
     if P == 1:
         return all_to_all(x, inner_axis, impl=impl, num_chunks=num_chunks)
     N = P * n

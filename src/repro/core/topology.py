@@ -63,6 +63,22 @@ class ChipSpec:
 
 V5E = ChipSpec()
 
+#: Specs by ``jax.Device.device_kind``.  TPU v5e (reported as "TPU v5
+#: lite"): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+#: 16 GB HBM at 819 GB/s per chip.
+CHIP_SPECS: dict[str, ChipSpec] = {"TPU v5 lite": V5E}
+
+
+def chip_spec(device_kind: str) -> ChipSpec:
+    """The :class:`ChipSpec` of a device kind; an unknown kind raises (no
+    device is silently priced as another)."""
+    if device_kind not in CHIP_SPECS:
+        raise ValueError(
+            f"no ChipSpec for device kind {device_kind!r}; known: "
+            f"{sorted(CHIP_SPECS)}"
+        )
+    return CHIP_SPECS[device_kind]
+
 
 @dataclasses.dataclass(frozen=True)
 class ClusterSpec:

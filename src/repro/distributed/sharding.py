@@ -127,9 +127,7 @@ def mesh_context(ctx: MeshContext | None) -> Iterator[MeshContext | None]:
     token = _CTX.set(ctx)
     try:
         if ctx is not None:
-            from repro.compat import set_mesh
-
-            with set_mesh(ctx.mesh):
+            with jax.set_mesh(ctx.mesh):
                 yield ctx
         else:
             yield None

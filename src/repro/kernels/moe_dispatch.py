@@ -7,7 +7,10 @@ computation is an inherently *sequential* running histogram over the token
 stream; this kernel carries the per-expert counters in VMEM scratch across a
 sequential grid (one pass over token blocks, no [T, E] cumsum materialized
 in HBM like the jnp reference does — that intermediate is T×E×4 bytes,
-~1 GB for olmoe's train cell).
+~1 GB for olmoe's train cell).  The within-block rank is the MXU
+triangular product of :func:`repro.kernels.hash_partition.block_rank`
+(the TPU lowering has no in-kernel ``cumsum``); the running counters ride
+on top of it as an ``[experts, 1]`` column.
 """
 
 from __future__ import annotations
@@ -19,17 +22,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .hash_partition import SUBLANES, as_blocks, block_rank, strict_upper
+
 
 def _dispatch_kernel(
-    dest_ref,   # [blk] int32
-    slot_ref,   # out [blk] int32
-    count_ref,  # out [1, E] int32 (final counts, clamped to capacity)
-    run_ref,    # scratch [1, E] int32 running histogram
+    dest_ref,   # [8, blk] int32: 8 blocks of token destinations
+    slot_ref,   # out [8, blk] int32
+    count_ref,  # out [E_pad, 1] int32 (final counts, clamped to capacity)
+    run_ref,    # scratch [E_pad, 1] f32 running histogram
     *,
     num_dest: int,
     capacity: int,
-    block: int,
-    num_blocks: int,
 ):
     i = pl.program_id(0)
 
@@ -37,50 +40,56 @@ def _dispatch_kernel(
     def _init():
         run_ref[...] = jnp.zeros_like(run_ref)
 
-    dest = dest_ref[...]  # [blk]
-    onehot = (
-        dest[:, None] == jax.lax.broadcasted_iota(jnp.int32, (block, num_dest), 1)
-    ).astype(jnp.int32)
-    within = jnp.cumsum(onehot, axis=0) - onehot      # rank within this block
-    base = run_ref[0]                                  # [E] counts before block
-    rank = jnp.sum(onehot * (within + base[None, :]), axis=1)
-    kept = rank < capacity
-    slot_ref[...] = jnp.where(kept, dest * capacity + rank, num_dest * capacity)
-    run_ref[0] = base + onehot.sum(axis=0)
+    upper = strict_upper(dest_ref.shape[1])
+    base = run_ref[...]  # [E_pad, 1] counts before this grid step
+    for r in range(SUBLANES):
+        dest = dest_ref[r:r + 1, :]
+        onehot, within, count = block_rank(dest, num_dest, upper)
+        rank = within + jnp.sum(onehot * base, axis=0, keepdims=True).astype(
+            jnp.int32
+        )
+        kept = rank < capacity
+        slot_ref[r:r + 1, :] = jnp.where(
+            kept, dest * capacity + rank, num_dest * capacity
+        )
+        base = base + count
+    run_ref[...] = base
 
-    @pl.when(i == num_blocks - 1)
+    @pl.when(i == pl.num_programs(0) - 1)
     def _finish():
-        count_ref[0] = jnp.minimum(run_ref[0], capacity)
+        count_ref[...] = jnp.minimum(base, capacity).astype(jnp.int32)
 
 
 def moe_dispatch(
     dest: jax.Array, num_dest: int, capacity: int, block: int = 256,
     interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
-    """(slot [T] int32, counts [num_dest] int32); overflow -> num_dest*capacity."""
+    """(slot [T] int32, counts [num_dest] int32); overflow -> num_dest*capacity.
+
+    Any ``T``: the tail is padded with the inert id ``num_dest`` (matches no
+    expert, slots to the drop bin) to whole ``(8, block)`` grid steps.
+    """
     T = dest.shape[0]
-    blk = min(block, T)
-    assert T % blk == 0, (T, blk)
-    nb = T // blk
+    d = as_blocks(dest, num_dest, block)
+    e_pad = -(-num_dest // SUBLANES) * SUBLANES
     kernel = functools.partial(
-        _dispatch_kernel, num_dest=num_dest, capacity=capacity, block=blk, num_blocks=nb
+        _dispatch_kernel, num_dest=num_dest, capacity=capacity
     )
+    spec = pl.BlockSpec((SUBLANES, block), lambda i: (i, 0))
     slot, counts = pl.pallas_call(
         kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((blk,), lambda i: (i,))],
-        out_specs=[
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((1, num_dest), lambda i: (0, 0)),
-        ],
+        grid=(d.shape[0] // SUBLANES,),
+        in_specs=[spec],
+        out_specs=[spec, pl.BlockSpec((e_pad, 1), lambda i: (0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((T,), jnp.int32),
-            jax.ShapeDtypeStruct((1, num_dest), jnp.int32),
+            jax.ShapeDtypeStruct(d.shape, jnp.int32),
+            jax.ShapeDtypeStruct((e_pad, 1), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, num_dest), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((e_pad, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(dest)
-    return slot, counts[0]
+    )(d)
+    return slot.reshape(-1)[:T], counts[:num_dest, 0]
 
 
 __all__ = ["moe_dispatch"]

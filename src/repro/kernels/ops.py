@@ -109,7 +109,7 @@ def ssd_scan(x, dt, A, Bm, Cm, chunk, initial_state=None):
 def hash_partition(keys, num_partitions, block=256):
     T = keys.shape[0]
     blk = min(block, T)
-    if kernels_enabled() and T % blk == 0:
+    if kernels_enabled() and T % blk == 0 and blk % 128 == 0:
         from .hash_partition import hash_partition as kern
 
         return kern(keys, num_partitions, block=blk, interpret=_interpret())
@@ -136,21 +136,21 @@ def partition_ranks(dest, num_bins, block=256):
     The fused-pack entry point: Pallas kernel per block (histogram +
     block-local rank), cheap XLA combine across blocks.  ``dest`` values
     outside ``[0, num_bins)`` get an arbitrary rank and count nowhere.
-    Handles arbitrary ``T`` by padding with an inert out-of-range id.
+    Handles arbitrary ``T`` by padding with an inert out-of-range id to a
+    whole number of blocks (the kernel's lane-aligned tiling).
     """
     T = dest.shape[0]
-    blk = min(block, T)
-    pad = (-T) % blk
+    pad = (-T) % block
     d = dest.astype(jnp.int32)
     if pad:
         d = jnp.concatenate([d, jnp.full((pad,), num_bins, jnp.int32)])
     if kernels_enabled():
         from .hash_partition import partition_pack as kern
 
-        hist, local = kern(d, num_bins, block=blk, interpret=_interpret())
+        hist, local = kern(d, num_bins, block=block, interpret=_interpret())
     else:
-        hist, local = R.partition_pack_ref(d, num_bins, block=blk)
-    rank = _combine_block_ranks(hist, local, d, blk)
+        hist, local = R.partition_pack_ref(d, num_bins, block=block)
+    rank = _combine_block_ranks(hist, local, d, block)
     return rank[:T], hist.sum(axis=0)
 
 
@@ -163,8 +163,7 @@ def hash_partition_ranks(keys, valid, num_partitions, block=256):
     meaningful to callers.
     """
     T = keys.shape[0]
-    blk = min(block, T)
-    pad = (-T) % blk
+    pad = (-T) % block
     k = keys.astype(jnp.int32)
     v = valid.astype(jnp.int32)
     if pad:
@@ -174,35 +173,25 @@ def hash_partition_ranks(keys, valid, num_partitions, block=256):
         from .hash_partition import hash_partition_pack as kern
 
         dest, hist, local = kern(
-            k, v, num_partitions, block=blk, interpret=_interpret()
+            k, v, num_partitions, block=block, interpret=_interpret()
         )
     else:
-        dest, hist, local = R.hash_partition_pack_ref(k, v, num_partitions, block=blk)
-    rank = _combine_block_ranks(hist, local, dest, blk)
+        dest, hist, local = R.hash_partition_pack_ref(k, v, num_partitions, block=block)
+    rank = _combine_block_ranks(hist, local, dest, block)
     return dest[:T], rank[:T], hist.sum(axis=0)
 
 
 def moe_dispatch(dest, num_dest, capacity, block=256):
     """(slot [T], counts [num_dest]); overflow/padding -> num_dest*capacity.
 
-    Arbitrary ``T``: rows are padded with the inert id ``num_dest`` (matches
-    no expert, ranks nowhere, slots to the drop bin) so the kernel's
-    block-grid contract holds — the decode-step dispatch ships a handful of
-    tokens per slot, far from any block multiple.
+    Arbitrary ``T``: the kernel pads with an inert id to whole blocks — the
+    decode-step dispatch ships a handful of tokens per slot, far from any
+    block multiple.
     """
-    T = dest.shape[0]
-    blk = min(block, T)
     if kernels_enabled():
         from .moe_dispatch import moe_dispatch as kern
 
-        pad = (-T) % blk
-        d = dest
-        if pad:
-            d = jnp.concatenate(
-                [d, jnp.full((pad,), num_dest, dest.dtype)]
-            )
-        slot, counts = kern(d, num_dest, capacity, block=blk, interpret=_interpret())
-        return slot[:T], counts
+        return kern(dest, num_dest, capacity, block=block, interpret=_interpret())
     return R.moe_dispatch_ref(dest, num_dest, capacity)
 
 

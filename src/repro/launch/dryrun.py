@@ -23,6 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_config, shapes_for
 from repro.configs.base import ModelConfig, ShapeSpec, SHAPES
+from repro.core.topology import chip_spec
 from repro.distributed.sharding import MeshContext, build_shardings, mesh_context
 from repro.launch import roofline as RL
 from repro.launch.mesh import make_context
@@ -103,10 +104,14 @@ def run_cell(
     arch: str,
     shape_name: str,
     multi_pod: bool,
+    device_kind: str,
     out_dir: str | None = None,
     overrides: dict | None = None,
     verbose: bool = True,
 ) -> dict:
+    """Compile one cell and price its roofline for ``device_kind`` (the
+    target chip's ``jax.Device.device_kind``; the fake devices the cell
+    compiles on are CPUs)."""
     shape = SHAPES[shape_name]
     overrides = dict(overrides or {})
     tag = overrides.pop("tag", "")
@@ -165,7 +170,7 @@ def run_cell(
         )
     api = R.build(cfg)
     art: dict = {"arch": arch, "shape": shape_name, "mesh": mesh_name, "chips": chips,
-                 "overrides": overrides, "tag": tag}
+                 "device_kind": device_kind, "overrides": overrides, "tag": tag}
     with mesh_context(ctx):
         fn, args, in_sh = build_cell(api, shape, ctx)
         t0 = time.perf_counter()
@@ -244,6 +249,9 @@ def main():
     p.add_argument("--shape", default="all", help="shape name or 'all'")
     p.add_argument("--mesh", default="both", choices=["single", "multi", "both"])
     p.add_argument("--out", default="artifacts/dryrun")
+    p.add_argument("--device-kind", required=True,
+                   help="target chip the rooflines are priced for, as JAX "
+                        "reports it (e.g. 'TPU v5 lite')")
     p.add_argument("--set", action="append", default=[],
                    help="cfg override key=value (e.g. exchange_impl=xla)")
     args = p.parse_args()
@@ -257,6 +265,7 @@ def main():
             pass
         overrides[k] = v
 
+    chip_spec(args.device_kind)  # an unknown kind fails before compiling
     archs = list(ARCH_IDS) if args.arch == "all" else [args.arch]
     meshes = {"single": [False], "multi": [True], "both": [False, True]}[args.mesh]
 
@@ -271,7 +280,8 @@ def main():
         for shape_name in shapes:
             for mp in meshes:
                 try:
-                    run_cell(arch, shape_name, mp, args.out, overrides or None)
+                    run_cell(arch, shape_name, mp, args.device_kind, args.out,
+                             overrides or None)
                 except Exception:
                     failures.append((arch, shape_name, mp))
                     print(f"FAILED: {arch} × {shape_name} × multi_pod={mp}")

@@ -17,6 +17,7 @@ import argparse
 import json
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs.trace import Tracer
 from repro.relational import datagen
 from repro.relational.context import ExecutionContext, StatsMode
@@ -48,6 +49,7 @@ def main():
     p.add_argument("--trace-dir", default=None,
                    help="write a Perfetto-loadable trace JSON per process")
     args = p.parse_args()
+    enable_compile_cache()
 
     tabs = datagen.gen_all(args.sf)
     templates = [tpch.ALL_QUERIES[name]() for name in args.mix.split(",")]

@@ -21,7 +21,7 @@ import dataclasses
 import re
 from typing import Any
 
-from repro.core.topology import V5E
+from repro.core.topology import ChipSpec, chip_spec
 
 _COLLECTIVE_RE = re.compile(
     r"=\s*(?:\(([^)]*)\)|((?:[a-z0-9]+)\[[^\]]*\](?:\{[^}]*\})?))\s*"
@@ -94,6 +94,7 @@ class RooflineTerms:
     coll_bytes_per_chip: dict[str, int]
     model_flops_global: float  # 6*N*D (or 6*N_active*D)
     chips: int
+    chip: ChipSpec  # the chip the terms are priced for
     ideal_bytes_global: float = 0.0  # mandatory HBM traffic of a perfect impl
     # Subset of coll_bytes_per_chip issued as async -start/-done pairs (the
     # collectives the latency-hiding scheduler is free to overlap).
@@ -101,21 +102,21 @@ class RooflineTerms:
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_chip / V5E.peak_flops_bf16
+        return self.flops_per_chip / self.chip.peak_flops_bf16
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_chip / V5E.hbm_bandwidth
+        return self.bytes_per_chip / self.chip.hbm_bandwidth
 
     @property
     def collective_s(self) -> float:
         total = sum(self.coll_bytes_per_chip.values())
-        return total / V5E.ici_link_bandwidth
+        return total / self.chip.ici_link_bandwidth
 
     @property
     def async_collective_s(self) -> float:
         total = sum(self.async_coll_bytes_per_chip.values())
-        return total / V5E.ici_link_bandwidth
+        return total / self.chip.ici_link_bandwidth
 
     @property
     def overlap_fraction(self) -> float:
@@ -157,8 +158,8 @@ class RooflineTerms:
         is compute-ideal; decode is bandwidth-ideal (must read the weights
         and the KV cache once per token no matter what).
         """
-        ideal_c = self.model_flops_global / self.chips / V5E.peak_flops_bf16
-        ideal_m = self.ideal_bytes_global / self.chips / V5E.hbm_bandwidth
+        ideal_c = self.model_flops_global / self.chips / self.chip.peak_flops_bf16
+        ideal_m = self.ideal_bytes_global / self.chips / self.chip.hbm_bandwidth
         return max(ideal_c, ideal_m)
 
     @property
@@ -237,6 +238,8 @@ def ideal_memory_bytes(cfg, shape, n_active: int, n_total: int, microbatches: in
 
 
 def from_artifact(art: dict) -> RooflineTerms:
+    """Terms of a dry-run artifact, priced for the chip its ``device_kind``
+    names (an unknown kind raises)."""
     return RooflineTerms(
         arch=art["arch"],
         shape=art["shape"],
@@ -246,6 +249,7 @@ def from_artifact(art: dict) -> RooflineTerms:
         coll_bytes_per_chip=art["collective_bytes"],
         model_flops_global=art["model_flops"],
         chips=art["chips"],
+        chip=chip_spec(art["device_kind"]),
         ideal_bytes_global=art.get("ideal_bytes", 0.0),
         async_coll_bytes_per_chip=art.get("async_collective_bytes", {}),
     )

@@ -23,6 +23,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry as R
 from repro.models.registry import VLM_PATCHES
 from repro.serve import (
@@ -88,6 +89,7 @@ def main(argv=None):
                         "(admission/prefill/decode-step spans; continuous "
                         "mode)")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     api = R.build(cfg)

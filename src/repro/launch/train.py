@@ -23,6 +23,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import ShapeSpec
 from repro.data import Prefetcher, make_batch_iterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry as R
 from repro.train import AdamWConfig, make_train_step
 from repro.train.step import TrainState
@@ -43,6 +44,7 @@ def main(argv=None):
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--log-every", type=int, default=10)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     cfg = cfg.scaled(num_microbatches=args.microbatches)

@@ -193,12 +193,11 @@ def _ep_moe_local(params, cfg: ModelConfig, x, axis_name: str,
     permutations on disjoint capacity slices — output is bit-identical for
     every chunk count dividing ``C``.
     """
-    from repro.compat import axis_size
     from repro.core.multiplexer import current_multiplexer
 
     mux = current_multiplexer()
-    m = axis_size(axis_name)
-    P_pods = axis_size(pod_axis) if pod_axis is not None else 1
+    m = jax.lax.axis_size(axis_name)
+    P_pods = jax.lax.axis_size(pod_axis) if pod_axis is not None else 1
     N = P_pods * m  # parallel units across BOTH network levels
     T_loc, d = x.shape
     E = cfg.num_experts

@@ -284,6 +284,55 @@ def q19_oracle(lineitem: Table, part: Table, terms=None) -> float:
     return total
 
 
+def expected(name: str, tabs: dict):
+    """The oracle answer of TPC-H template ``name`` (default parameters)
+    over ``tabs``, the :func:`~repro.relational.datagen.gen_all` dict."""
+    li, part = tabs["lineitem"], tabs["part"]
+    cust, orders = tabs["customer"], tabs["orders"]
+    return {
+        "q1": lambda: q1_oracle(li),
+        "q3": lambda: q3_oracle(cust, orders, li),
+        "q4": lambda: q4_oracle(li, orders),
+        "q6": lambda: q6_oracle(li),
+        "q12": lambda: q12_oracle(li, orders),
+        "q14": lambda: q14_oracle(li, part),
+        "q17": lambda: q17_oracle(li, part),
+        "q18": lambda: q18_oracle(li, orders, cust),
+        "q19": lambda: q19_oracle(li, part),
+    }[name]()
+
+
+def matches(name: str, got, want) -> bool:
+    """Whether an engine result ``got`` answers template ``name`` as
+    ``want`` does.  f32 money sums agree within ``rtol`` 1e-4 (Q1, Q6) or
+    1e-3 (Q14, Q17, Q19); counts agree; top-k answers name the same keys
+    (Q3 in rank order, Q18 with the same ``o_totalprice`` per order)."""
+    if name == "q1":
+        return all(
+            np.allclose(np.asarray(got[k]), want[k], rtol=1e-4) for k in want
+        )
+    if name == "q6":
+        return bool(np.allclose(float(got), want, rtol=1e-4))
+    if name in ("q14", "q17", "q19"):
+        return bool(np.allclose(float(got), want, rtol=1e-3))
+    if name == "q3":
+        return [int(k) for k in got["o_orderkey"]] == \
+            [int(k) for k in want["o_orderkey"]]
+    if name == "q4":
+        return bool(np.allclose(np.asarray(got["order_count"]), want))
+    if name == "q12":
+        return all(
+            np.allclose(got[k], want[k])
+            for k in ("high_line_count", "low_line_count")
+        )
+    if name == "q18":
+        return dict(zip(np.asarray(got["o_orderkey"]).tolist(),
+                        np.asarray(got["o_totalprice"]).tolist())) == \
+            dict(zip(np.asarray(want["o_orderkey"]).tolist(),
+                     np.asarray(want["o_totalprice"]).tolist()))
+    raise KeyError(name)
+
+
 __all__ = ["q1_oracle", "q6_oracle", "q17_oracle", "q3_oracle",
            "q14_oracle", "q19_oracle", "q4_oracle", "q12_oracle",
-           "q18_oracle"]
+           "q18_oracle", "expected", "matches"]

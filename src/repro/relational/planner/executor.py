@@ -31,7 +31,7 @@ import warnings
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...compat import fetch, make_mesh, shard_map
 from ...core import exchange as core_exchange
@@ -68,6 +68,35 @@ def _prep(table: Table, num_shards: int) -> Table:
     return shard_rows(pad_to(table, cap), num_shards)
 
 
+def _place(table: Table, num_shards: int, mesh, axes) -> tuple[dict, jax.Array]:
+    """Prep a base table and put its ``(columns, valid)`` on the mesh once,
+    row-sharded the way the shard_map reads them — a dispatch then moves no
+    table data (on one device this is a no-op)."""
+    t = _prep(table, num_shards)
+    return jax.device_put((t.columns, t.valid), NamedSharding(mesh, P(axes)))
+
+
+def resolve_knobs(
+    tuned, impl: str, pack_impl: str | None, num_chunks: int | None
+) -> dict:
+    """The multiplexer knobs for a context's pins over a tuned config.
+
+    ``impl="auto"`` applies the tuned knobs, with any explicitly passed
+    knob pinned over the tuner's choice.  An explicit ``impl`` uses the
+    caller's knobs verbatim with the pre-tuner defaults for anything unset.
+    Returns ``make_multiplexer`` keyword arguments.
+    """
+    if impl == "auto":
+        return dict(
+            impl=tuned.impl,
+            pack_impl=pack_impl or tuned.pack_impl,
+            pipeline_chunks=num_chunks or tuned.pipeline_chunks,
+            transport_chunks=tuned.transport_chunks,
+        )
+    return dict(impl=impl, pack_impl=pack_impl or "xla",
+                pipeline_chunks=num_chunks or 1)
+
+
 def _make_mux(
     mesh,
     plan: PhysicalPlan,
@@ -75,29 +104,15 @@ def _make_mux(
     pack_impl: str | None,
     num_chunks: int | None,
 ) -> CommMultiplexer:
-    """One multiplexer per query.
-
-    ``impl="auto"`` applies the PLAN-TIME tuned knobs (so ``explain()``
-    describes exactly what runs), with any explicitly passed knob pinned
-    over the tuner's choice.  An explicit ``impl`` uses the caller's knobs
-    verbatim with the pre-tuner defaults for anything unset.  The
-    ``cross_pod`` strategy is a plan shape (see ``plan_physical``), so the
-    mux just records the plan's resolved choice for introspection.
+    """One multiplexer per query, from the PLAN-TIME tuned knobs (so
+    ``explain()`` describes exactly what runs) and the caller's pins (see
+    :func:`resolve_knobs`).  The ``cross_pod`` strategy is a plan shape
+    (see ``plan_physical``), so the mux just records the plan's resolved
+    choice for introspection.
     """
-    resolved = plan.tuned.cross_pod or "broadcast"
-    if impl == "auto":
-        t = plan.tuned
-        return make_multiplexer(
-            mesh,
-            impl=t.impl,
-            pack_impl=pack_impl or t.pack_impl,
-            pipeline_chunks=num_chunks or t.pipeline_chunks,
-            transport_chunks=t.transport_chunks,
-            cross_pod=resolved,
-        )
     return make_multiplexer(
-        mesh, impl=impl, pack_impl=pack_impl or "xla",
-        pipeline_chunks=num_chunks or 1, cross_pod=resolved,
+        mesh, **resolve_knobs(plan.tuned, impl, pack_impl, num_chunks),
+        cross_pod=plan.tuned.cross_pod or "broadcast",
     )
 
 
@@ -420,7 +435,6 @@ def compile_plan(
         ctx.trace.add_span(
             f"mux:{plan.name}", cat="compile", **mux.describe()
         )
-    prepped = [_prep(tables[name], num_shards) for name in plan.scans]
     single = num_shards == 1 and num_pods == 1
     report_keys = _report_keys(plan.root)
 
@@ -553,8 +567,8 @@ def compile_plan(
         return result, dropped, reports
 
     flat = []
-    for t in prepped:
-        flat.extend((t.columns, t.valid))
+    for name in plan.scans:
+        flat.extend(_place(tables[name], num_shards, mesh, axes))
     fn = shard_map(
         body,
         mesh=mesh,
@@ -660,5 +674,6 @@ __all__ = [
     "_mesh",
     "_axes",
     "_prep",
+    "_place",
     "_make_mux",
 ]

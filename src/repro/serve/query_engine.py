@@ -51,7 +51,7 @@ from repro.core.topology import ChipSpec, V5E
 from repro.obs.trace import QueryTrace, deposit, maybe_span
 from repro.relational import stats as rstats
 from repro.relational.context import ExecutionContext, StatsMode, require_context
-from repro.relational.planner.executor import _mesh
+from repro.relational.planner.executor import _mesh, resolve_knobs
 from repro.relational.planner.physical import PhysicalPlan, plan_physical
 from repro.relational.planner.plan_cache import PlanCache, PlanKey, plan_key
 from repro.relational.planner.tpch import PlannedQuery
@@ -164,7 +164,9 @@ class QueryServeEngine:
 
     def _ensure_mux(self):
         """The one shared multiplexer, tuned over every registered plan's
-        exchange shapes the first time an executor needs it."""
+        exchange shapes the first time an executor needs it.  The context's
+        pinned knobs apply over the tuner's by the rule ``compile_plan``
+        uses (:func:`~repro.relational.planner.executor.resolve_knobs`)."""
         if self._mux is None:
             tuned = tune_shared_config(
                 self.num_shards,
@@ -174,12 +176,10 @@ class QueryServeEngine:
                 topology=self.topology,
             )
             self.shared_tuned = tuned
+            ctx = self.ctx
             self._mux = make_multiplexer(
                 _mesh(self.num_shards, self.num_pods),
-                impl=tuned.impl,
-                pack_impl=tuned.pack_impl,
-                pipeline_chunks=tuned.pipeline_chunks,
-                transport_chunks=tuned.transport_chunks,
+                **resolve_knobs(tuned, ctx.impl, ctx.pack_impl, ctx.num_chunks),
             )
             if self.ctx.trace is not None:
                 self.ctx.trace.add_span(

@@ -120,7 +120,7 @@ def test_moe_dispatch_matches_oracle(T, E, C):
 
 
 @pytest.mark.parametrize("T,B,blk", [(512, 9, 256), (300, 5, 128), (64, 3, 256),
-                                     (1000, 17, 256)])
+                                     (1000, 17, 256), (2048, 257, 256)])
 def test_partition_ranks_matches_arrival_order(T, B, blk):
     dest = jax.random.randint(jax.random.fold_in(KEY, T), (T,), 0, B)
     rank, counts = ops.partition_ranks(dest, B, block=blk)
@@ -139,7 +139,7 @@ def test_partition_ranks_matches_arrival_order(T, B, blk):
     np.testing.assert_array_equal(np.asarray(counts), np.bincount(d, minlength=B))
 
 
-@pytest.mark.parametrize("T,P", [(512, 8), (300, 5), (64, 3)])
+@pytest.mark.parametrize("T,P", [(512, 8), (300, 5), (64, 3), (1024, 256)])
 def test_hash_partition_ranks_fused_matches_ref(T, P):
     keys = jax.random.randint(KEY, (T,), 0, 1 << 30)
     valid = jax.random.bernoulli(jax.random.fold_in(KEY, 9), 0.8, (T,)).astype(jnp.int32)
