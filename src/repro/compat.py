@@ -2,7 +2,8 @@
 
 ``make_mesh`` fixes the axis types to Auto, ``shard_map`` keeps the repo's
 keyword spelling, ``enable_cpu_collectives`` switches on Gloo for the
-multi-process CPU harness, and ``fetch`` reads arrays that span processes.
+multi-process CPU harness, and ``fetch`` reads arrays that span processes,
+one ``repro.transfer`` span per device-to-host read.
 """
 
 from __future__ import annotations
@@ -12,6 +13,8 @@ from typing import Any, Sequence
 
 import jax
 import numpy as np
+
+from .obs.trace import maybe_span
 
 
 def make_mesh(
@@ -64,19 +67,21 @@ def fetch(x: Any):
 
     Addressable or fully replicated arrays read directly; an array sharded
     over devices this process cannot address is all-gathered across
-    processes.  Pytrees are mapped leaf-wise.
+    processes.  Pytrees are mapped leaf-wise, and each device array's read
+    is one ``repro.transfer`` span.
     """
 
     def one(leaf):
-        if (
-            not hasattr(leaf, "sharding")  # numpy / python scalar
-            or leaf.is_fully_addressable
-            or leaf.is_fully_replicated
-        ):
+        if not hasattr(leaf, "sharding"):  # numpy / python scalar
             return np.asarray(leaf)
-        from jax.experimental import multihost_utils
+        with maybe_span(None, "repro.transfer", bytes=leaf.nbytes):
+            if leaf.is_fully_addressable or leaf.is_fully_replicated:
+                return np.asarray(leaf)
+            from jax.experimental import multihost_utils
 
-        return np.asarray(multihost_utils.process_allgather(leaf, tiled=True))
+            return np.asarray(
+                multihost_utils.process_allgather(leaf, tiled=True)
+            )
 
     return jax.tree.map(one, x)
 

@@ -52,6 +52,11 @@ relational layer (:mod:`repro.relational.distributed`) sizes capacity to
 the static zero-drop bound and raises on any nonzero count: overflow is an
 error, never silent row loss.
 
+The hash shuffles name their phases on the device trace: each op runs
+under a ``jax.named_scope`` of ``hash`` (destination of each row),
+``pack`` (rank and scatter into message buffers; the fused Pallas kernel
+hashes here too), ``ship`` (the transport) or ``unpack`` (reassembly).
+
 Everything here must be called inside ``shard_map`` (a named mesh axis in
 scope).  The pjit/auto-sharded layers above call these through
 :mod:`repro.core.multiplexer`, which owns the knob *values* — hand-set or
@@ -449,12 +454,19 @@ def hash_shuffle(
         if pack_impl == "pallas":
             from repro.kernels import ops as kernel_ops
 
-            dest, my_rank, counts_all = kernel_ops.hash_partition_ranks(
-                keys_c, valid_c.astype(jnp.int32), n
+            with jax.named_scope("pack"):
+                dest, my_rank, counts_all = kernel_ops.hash_partition_ranks(
+                    keys_c, valid_c.astype(jnp.int32), n
+                )
+                return _scatter_pack(
+                    dest, my_rank, counts_all, data_c, n, cap_c, valid_c
+                )
+        with jax.named_scope("hash"):
+            dest = (fibonacci_hash(keys_c) % jnp.uint32(n)).astype(jnp.int32)
+        with jax.named_scope("pack"):
+            return pack_by_destination(
+                dest, data_c, n, cap_c, valid=valid_c, impl=pack_impl
             )
-            return _scatter_pack(dest, my_rank, counts_all, data_c, n, cap_c, valid_c)
-        dest = (fibonacci_hash(keys_c) % jnp.uint32(n)).astype(jnp.int32)
-        return pack_by_destination(dest, data_c, n, cap_c, valid=valid_c, impl=pack_impl)
 
     # Double-buffered pipeline: the pack of chunk c+1 is issued before the
     # ppermute phases of chunk c and has no data dependence on them, so the
@@ -466,27 +478,29 @@ def hash_shuffle(
         bufs, counts, dropped_c = packed
         if c + 1 < num_chunks:
             packed = pack(c + 1)
-        shuffled_chunks.append(
-            all_to_all(bufs, axis_name, impl=impl, num_chunks=transport_chunks)
-        )
-        counts_chunks.append(
-            all_to_all(counts.reshape(n, 1), axis_name, impl=impl).reshape(n)
-        )
+        with jax.named_scope("ship"):
+            shuffled_chunks.append(all_to_all(
+                bufs, axis_name, impl=impl, num_chunks=transport_chunks
+            ))
+            counts_chunks.append(
+                all_to_all(counts.reshape(n, 1), axis_name, impl=impl).reshape(n)
+            )
         dropped = dropped + dropped_c
 
-    if num_chunks == 1:
-        shuffled, counts_in = shuffled_chunks[0], counts_chunks[0]
-        rows_out = shuffled.reshape((n * capacity,) + shuffled.shape[2:])
-        valid_out = (
-            jnp.arange(cap_c)[None, :] < counts_in[:, None]
-        ).reshape(n * capacity)
-    else:
-        stacked = jnp.stack(shuffled_chunks, axis=1)  # [n, C, cap_c, row...]
-        rows_out = stacked.reshape((n * capacity,) + stacked.shape[3:])
-        counts_in = jnp.stack(counts_chunks, axis=1)  # [n, C]
-        valid_out = (
-            jnp.arange(cap_c)[None, None, :] < counts_in[:, :, None]
-        ).reshape(n * capacity)
+    with jax.named_scope("unpack"):
+        if num_chunks == 1:
+            shuffled, counts_in = shuffled_chunks[0], counts_chunks[0]
+            rows_out = shuffled.reshape((n * capacity,) + shuffled.shape[2:])
+            valid_out = (
+                jnp.arange(cap_c)[None, :] < counts_in[:, None]
+            ).reshape(n * capacity)
+        else:
+            stacked = jnp.stack(shuffled_chunks, axis=1)  # [n, C, cap_c, row...]
+            rows_out = stacked.reshape((n * capacity,) + stacked.shape[3:])
+            counts_in = jnp.stack(counts_chunks, axis=1)  # [n, C]
+            valid_out = (
+                jnp.arange(cap_c)[None, None, :] < counts_in[:, :, None]
+            ).reshape(n * capacity)
     return rows_out, valid_out, lax.psum(dropped, axis_name)
 
 
@@ -521,20 +535,30 @@ def hash_shuffle_spill(
     if pack_impl == "pallas":
         from repro.kernels import ops as kernel_ops
 
-        dest, my_rank, counts_all = kernel_ops.hash_partition_ranks(
-            keys, valid.astype(jnp.int32), n
-        )
+        with jax.named_scope("pack"):
+            dest, my_rank, counts_all = kernel_ops.hash_partition_ranks(
+                keys, valid.astype(jnp.int32), n
+            )
     else:
-        dest = (fibonacci_hash(keys) % jnp.uint32(n)).astype(jnp.int32)
-        dest = jnp.where(valid, dest, n)
-        my_rank, counts_all = _rank_by_destination(dest, n, pack_impl)
-    spilled = valid & (my_rank >= capacity)
-    deliver = valid & ~spilled
-    bufs, counts, _ = _scatter_pack(dest, my_rank, counts_all, rows, n, capacity, deliver)
-    shuffled = all_to_all(bufs, axis_name, impl=impl)
-    counts_in = all_to_all(counts.reshape(n, 1), axis_name, impl=impl).reshape(n)
-    rows_out = shuffled.reshape((n * capacity,) + shuffled.shape[2:])
-    valid_out = (jnp.arange(capacity)[None, :] < counts_in[:, None]).reshape(n * capacity)
+        with jax.named_scope("hash"):
+            dest = (fibonacci_hash(keys) % jnp.uint32(n)).astype(jnp.int32)
+            dest = jnp.where(valid, dest, n)
+        with jax.named_scope("pack"):
+            my_rank, counts_all = _rank_by_destination(dest, n, pack_impl)
+    with jax.named_scope("pack"):
+        spilled = valid & (my_rank >= capacity)
+        deliver = valid & ~spilled
+        bufs, counts, _ = _scatter_pack(
+            dest, my_rank, counts_all, rows, n, capacity, deliver
+        )
+    with jax.named_scope("ship"):
+        shuffled = all_to_all(bufs, axis_name, impl=impl)
+        counts_in = all_to_all(counts.reshape(n, 1), axis_name, impl=impl).reshape(n)
+    with jax.named_scope("unpack"):
+        rows_out = shuffled.reshape((n * capacity,) + shuffled.shape[2:])
+        valid_out = (
+            jnp.arange(capacity)[None, :] < counts_in[:, None]
+        ).reshape(n * capacity)
     return rows_out, valid_out, spilled
 
 
@@ -604,9 +628,11 @@ def hash_shuffle_two_level(
         valid = jnp.ones((T,), jnp.bool_)
 
     # Hop 1: pack by destination pod, one rank computation for keys + rows.
-    gdest = (fibonacci_hash(keys) % jnp.uint32(N)).astype(jnp.int32)
-    dest_pod = jnp.where(valid, gdest // n, P)  # invalid -> overflow bucket
-    my_rank, counts_all = _rank_by_destination(dest_pod, P, pack_impl)
+    with jax.named_scope("hash"):
+        gdest = (fibonacci_hash(keys) % jnp.uint32(N)).astype(jnp.int32)
+        dest_pod = jnp.where(valid, gdest // n, P)  # invalid -> overflow bucket
+    with jax.named_scope("pack"):
+        my_rank, counts_all = _rank_by_destination(dest_pod, P, pack_impl)
     # Coarse shift phases over the pod axis (the multiplexer connections of
     # the paper): scheduled transports use the shift schedule — valid for
     # every P, unlike one_factorization — and "xla" keeps the monolithic
@@ -616,25 +642,32 @@ def hash_shuffle_two_level(
         # Ship keys as an extra leading column of the row matrix: one phase
         # sequence over the slowest network instead of two.  (This is the
         # relational hot path — int32 keys, packed int32 rows.)
-        aug = jnp.concatenate([keys[:, None], rows], axis=1)
-        aug_bufs, counts, drop1 = _scatter_pack(
-            dest_pod, my_rank, counts_all, aug, P, T, valid
-        )
-        aug_in = all_to_all(aug_bufs, outer_axis, impl=hop1)
-        keys_in, rows_in = aug_in[:, :, 0], aug_in[:, :, 1:]
+        with jax.named_scope("pack"):
+            aug = jnp.concatenate([keys[:, None], rows], axis=1)
+            aug_bufs, counts, drop1 = _scatter_pack(
+                dest_pod, my_rank, counts_all, aug, P, T, valid
+            )
+        with jax.named_scope("ship"):
+            aug_in = all_to_all(aug_bufs, outer_axis, impl=hop1)
+        with jax.named_scope("unpack"):
+            keys_in, rows_in = aug_in[:, :, 0], aug_in[:, :, 1:]
     else:
-        key_bufs, counts, drop1 = _scatter_pack(
-            dest_pod, my_rank, counts_all, keys, P, T, valid
-        )
-        row_bufs, _, _ = _scatter_pack(
-            dest_pod, my_rank, counts_all, rows, P, T, valid
-        )
-        keys_in = all_to_all(key_bufs, outer_axis, impl=hop1)
-        rows_in = all_to_all(row_bufs, outer_axis, impl=hop1)
-    counts_in = all_to_all(counts.reshape(P, 1), outer_axis, impl=hop1)
-    valid_in = (
-        jnp.arange(T)[None, :] < counts_in.reshape(P)[:, None]
-    ).reshape(P * T)
+        with jax.named_scope("pack"):
+            key_bufs, counts, drop1 = _scatter_pack(
+                dest_pod, my_rank, counts_all, keys, P, T, valid
+            )
+            row_bufs, _, _ = _scatter_pack(
+                dest_pod, my_rank, counts_all, rows, P, T, valid
+            )
+        with jax.named_scope("ship"):
+            keys_in = all_to_all(key_bufs, outer_axis, impl=hop1)
+            rows_in = all_to_all(row_bufs, outer_axis, impl=hop1)
+    with jax.named_scope("ship"):
+        counts_in = all_to_all(counts.reshape(P, 1), outer_axis, impl=hop1)
+    with jax.named_scope("unpack"):
+        valid_in = (
+            jnp.arange(T)[None, :] < counts_in.reshape(P)[:, None]
+        ).reshape(P * T)
 
     # Hop 2: ordinary in-pod shuffle.  n | N makes hash % n the correct
     # in-pod owner for rows from any source pod.

@@ -86,8 +86,8 @@ def main(argv=None):
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--trace-dir", default=None,
                    help="write a Perfetto-loadable trace JSON per process "
-                        "(admission/prefill/decode-step spans; continuous "
-                        "mode)")
+                        "(repro.round/repro.prefill/repro.decode spans; "
+                        "continuous mode)")
     args = p.parse_args(argv)
     enable_compile_cache()
 

@@ -1,5 +1,5 @@
-"""Observability: the telemetry spine (spans, counters, query traces),
-Perfetto export, and the model-vs-measured gate.
+"""Observability: the telemetry spine (spans on the profiler's clock,
+counters, query traces), Perfetto export, and the model-vs-measured gate.
 
 Import surface is deliberately lazy-friendly: :mod:`repro.obs.trace` has no
 repro dependencies (executors import it freely), :mod:`repro.obs.export`
@@ -16,6 +16,7 @@ from .trace import (  # noqa: F401
     deposit,
     maybe_span,
     model_error,
+    span_args,
 )
 
 __all__ = [
@@ -26,4 +27,5 @@ __all__ = [
     "deposit",
     "maybe_span",
     "model_error",
+    "span_args",
 ]

@@ -1,46 +1,50 @@
-"""One telemetry spine: host-side spans + per-query device counters.
+"""One telemetry spine: spans on the profiler's clock + per-query device counters.
 
-The repo's measurement story used to be a pile of one-off side channels
-(``run.exchange_report`` mutated on a function attribute, ``run.stats`` on
-the streamed runner, ``ttfr_s`` fields on serve requests).  This module is
-the one home for all of it, mirroring the paper's own discipline — every
-design claim in Rödiger §4–§6 is justified by a per-phase timing or a
-bandwidth-utilization number, so the repro records both, per query:
+* Spans — :func:`maybe_span` and :meth:`Tracer.span` always enter a
+  ``jax.profiler.TraceAnnotation``, so every span lands in the JAX
+  profiler's trace when a profiler session is on, on the same clock as the
+  device's ops; a gap in the device timeline can then be read against
+  what the host was doing.  With no session the annotation costs about a
+  microsecond.  Names are stable and start with ``repro.``
+  (``repro.round``, ``repro.plan``, ``repro.fetch``, ...); round numbers,
+  template names and request ids go in the args, never in the name.
+  :func:`span_args` adds args to every span opened inside it (the serving
+  engine tags each request's stages with ``req``, ``query`` and
+  ``tenant``).
 
-* :class:`Tracer` — nested host-side spans (plan → compile → pass → morsel
-  → exchange → drain-round on the query side; admission round / prefill /
-  decode step on the serve side) plus a thread-safe registry of counters,
-  gauges and histograms.  Attach one via the frozen
-  ``ExecutionContext.trace`` knob: the field is ``compare=False`` so a
-  traced and an untraced context hash equal — tracing never invalidates a
-  plan-cache or executor-memo entry, and never changes what runs inside
-  the jit (device counters are ALWAYS on; the tracer only decides whether
-  anyone writes them down).
+* :class:`Tracer` — attach one via the frozen ``ExecutionContext.trace``
+  knob and every span is also kept in memory (nested per thread), next to
+  a thread-safe registry of counters, gauges and histograms; this is what
+  :mod:`repro.obs.export` writes as a Perfetto timeline.  The field is
+  ``compare=False`` so a traced and an untraced context hash equal —
+  tracing never invalidates a plan-cache or executor-memo entry, and never
+  changes what runs inside the jit (device counters are ALWAYS on; the
+  tracer only decides whether anyone writes them down).
 
 * :class:`QueryTrace` — the per-run record of what the devices measured:
   one :class:`ExchangeEdge` per shuffle (destination histogram psum'd
   inside the jit, measured vs modeled wire bytes, the autotuner's
-  predicted makespan next to measured wall time, salted/plain decision)
-  plus the streamed path's spill/drain/prefetch counters.  Returned
-  per-run from ``runner.collect(out)`` — the fix for the old
-  ``run.exchange_report`` attribute, which concurrent serve rounds
-  clobbered — and still readable through that attribute as a
-  deprecation-warned view.
+  predicted makespan, salted/plain decision) plus the streamed path's
+  spill/drain/prefetch counters.  Returned per-run from
+  ``runner.collect(out)``; :func:`deposit` files it and its byte counters
+  into a tracer.
 
-Span timestamps are wall-clock epoch seconds (``time.time``) so traces
-from different processes of one Gloo cluster merge onto a single timeline;
-durations come from the same clock, which is plenty for the >100µs spans
-recorded here.  Export to JSON / Chrome trace-event lives in
-:mod:`repro.obs.export`.
+In-memory span timestamps are wall-clock epoch seconds (``time.time``),
+the clock the profiler stamps its events with, so traces from different
+processes of one Gloo cluster merge onto one timeline and line up with a
+profiler trace of the same run.
 """
 
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import dataclasses
 import threading
 import time
 from typing import Any, Iterator, Mapping
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Span",
@@ -48,6 +52,7 @@ __all__ = [
     "ExchangeEdge",
     "QueryTrace",
     "maybe_span",
+    "span_args",
     "model_error",
     "deposit",
 ]
@@ -80,6 +85,33 @@ def model_error(predicted: float | None, measured: float | None) -> float | None
 # ---------------------------------------------------------------------------
 # Spans.
 # ---------------------------------------------------------------------------
+
+# What a span opened in this context inherits: the tracer of the enclosing
+# traced span, and the args of the enclosing :func:`span_args` blocks.
+_INHERITED: contextvars.ContextVar[tuple["Tracer | None", dict]] = (
+    contextvars.ContextVar("repro_span_context", default=(None, {}))
+)
+
+
+@contextlib.contextmanager
+def span_args(**args: Any):
+    """Add ``args`` to every span opened inside the ``with`` block, on this
+    thread (the serving engine tags a request's stages this way, so spans
+    opened deep in the executor name the request too)."""
+    tracer, inherited = _INHERITED.get()
+    token = _INHERITED.set((tracer, {**inherited, **args}))
+    try:
+        yield
+    finally:
+        _INHERITED.reset(token)
+
+
+def _annotation(name: str, args: dict) -> TraceAnnotation:
+    """The profiler event of a span: scalar args only."""
+    return TraceAnnotation(
+        name,
+        **{k: v for k, v in args.items() if isinstance(v, (str, int, float))},
+    )
 
 
 @dataclasses.dataclass
@@ -135,48 +167,33 @@ class Tracer:
             st = self._tls.stack = []
         return st
 
-    def _attach(self, span: Span) -> None:
-        stack = self._stack()
-        if stack:
-            stack[-1].children.append(span)
-        else:
-            with self._lock:
-                self.spans.append(span)
-
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "host", **args: Any):
-        """Open a nested span around a ``with`` block."""
+        """Open a nested span around a ``with`` block: kept in memory and
+        entered as a profiler annotation of the same name and args.  Spans
+        opened inside it without a tracer are kept by this one too."""
+        _, inherited = _INHERITED.get()
+        args = {**inherited, **args}
         s = Span(
             name=name, cat=cat, t0=time.time(), dur=None,
-            pid=self.pid, tid=threading.get_ident(), args=dict(args),
+            pid=self.pid, tid=threading.get_ident(), args=args,
         )
-        self._attach(s)
-        self._stack().append(s)
+        stack = self._stack()
+        if stack:
+            stack[-1].children.append(s)
+        else:
+            with self._lock:
+                self.spans.append(s)
+        stack.append(s)
+        token = _INHERITED.set((self, inherited))
         t0 = time.perf_counter()
         try:
-            yield s
+            with _annotation(name, args):
+                yield s
         finally:
             s.dur = time.perf_counter() - t0
-            self._stack().pop()
-
-    def add_span(
-        self,
-        name: str,
-        cat: str = "host",
-        t0: float | None = None,
-        dur: float = 0.0,
-        **args: Any,
-    ) -> Span:
-        """Record a span post-hoc (e.g. per-edge exchange spans laid out
-        inside an already-measured execute window).  Nests under the
-        current thread's open span, if any."""
-        s = Span(
-            name=name, cat=cat, t0=time.time() if t0 is None else t0,
-            dur=dur, pid=self.pid, tid=threading.get_ident(),
-            args=dict(args),
-        )
-        self._attach(s)
-        return s
+            _INHERITED.reset(token)
+            stack.pop()
 
     # -- metrics ------------------------------------------------------------
 
@@ -201,10 +218,15 @@ class Tracer:
 
 @contextlib.contextmanager
 def maybe_span(tracer: Tracer | None, name: str, cat: str = "host", **args):
-    """``tracer.span(...)`` when a tracer is attached, else a no-op — the
-    one-liner every traced call site uses so untraced runs pay nothing."""
+    """A span around a ``with`` block: ``tracer.span(...)`` with ``tracer``,
+    or else with the tracer of the enclosing traced span; with neither, the
+    profiler annotation alone (about a microsecond when no profiler session
+    is on).  Yields the in-memory :class:`Span`, or None without a tracer."""
+    inherited_tracer, inherited = _INHERITED.get()
+    tracer = tracer if tracer is not None else inherited_tracer
     if tracer is None:
-        yield None
+        with _annotation(name, {**inherited, **args}):
+            yield None
         return
     with tracer.span(name, cat=cat, **args) as s:
         yield s
@@ -305,34 +327,12 @@ class QueryTrace:
 
 
 def deposit(tracer: Tracer | None, qt: QueryTrace) -> None:
-    """Write one run's QueryTrace into a tracer: the record itself, one
-    ``exchange:`` span per edge (laid out inside the measured window when
-    one is known), and byte counters.  No-op without a tracer."""
+    """File one run's QueryTrace in a tracer: the record itself and its
+    byte and run counters.  No-op without a tracer."""
     if tracer is None:
         return
     tracer.add_query_trace(qt)
-    now = time.time()
-    window = qt.measured_s
-    t0 = now - window if window is not None else now
-    shares = [e.predicted_s or 0.0 for e in qt.edges]
-    total_share = sum(shares) or float(len(qt.edges) or 1)
-    at = t0
-    for e, share in zip(qt.edges, shares):
-        dur = (
-            (window or 0.0) * (share / total_share)
-            if window is not None
-            else (e.measured_s or 0.0)
-        )
-        tracer.add_span(
-            f"exchange:{e.key}", cat="exchange", t0=at, dur=dur,
-            query=qt.query, measured_bytes=e.measured_bytes,
-            modeled_wire_bytes=e.modeled_wire_bytes,
-            byte_model_err=e.byte_model_err,
-            predicted_s=e.predicted_s, measured_s=e.measured_s,
-            time_model_err=e.time_model_err,
-            overload=e.overload, salted=e.salted,
-        )
-        at += dur
+    for e in qt.edges:
         tracer.counter("exchange.measured_bytes", e.measured_bytes)
         tracer.counter("exchange.modeled_wire_bytes", e.modeled_wire_bytes)
     tracer.counter(f"query.{qt.query}.runs", 1.0)

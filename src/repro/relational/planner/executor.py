@@ -20,6 +20,12 @@ carries fine-grained traffic), broadcast edges obey the tuned
 ``cross_pod`` strategy (replicate, or hash-reshard by the build key), and
 psum/top-k combines cross both axes.  Plans are mesh-shape-agnostic; only
 this module touches devices.
+
+Names on the device trace: the program is named after the plan
+(``jit_q3``), and each physical node's ops run under a ``jax.named_scope``
+of its kind (:data:`SCOPES`), so a device op's ``op_name`` says which
+operator it belongs to.  Scopes are metadata: the compiled program is the
+same with or without them.
 """
 
 from __future__ import annotations
@@ -36,12 +42,31 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ...compat import fetch, make_mesh, shard_map
 from ...core import exchange as core_exchange
 from ...core.multiplexer import CommMultiplexer, make_multiplexer
-from ...obs.trace import QueryTrace
+from ...obs.trace import QueryTrace, maybe_span
 from .. import operators as ops
 from ..table import Table, pad_to, shard_rows
 from .physical import PhysicalPlan, PNode
 
 SHUFFLE_AXIS = "q"  # the in-pod (fast network) exchange axis
+
+# The named scope of each physical node kind's ops; exchanges are scoped by
+# ``exkind`` (``shuffle``/``broadcast``), and a join's payload gather runs
+# in a ``gather_payload`` scope inside ``join_pk``.
+SCOPES = {
+    "scan": "scan",
+    "filter": "filter",
+    "project": "project",
+    "join": "join_pk",
+    "groupby_sorted": "groupby_sorted",
+    "groupby_combine": "groupby_combine",
+    "groupby_dense": "groupby_dense",
+    "aggregate": "aggregate",
+    "topk": "topk",
+}
+
+
+def _scope(n: PNode) -> str:
+    return n.info["exkind"] if n.kind == "exchange" else SCOPES[n.kind]
 
 
 def _mesh(num_shards: int, num_pods: int = 1):
@@ -428,13 +453,13 @@ def compile_plan(
     mesh = _mesh(num_shards, num_pods)
     axes = _axes(num_pods)
     if mux is None:
-        mux = _make_mux(mesh, plan, impl, pack_impl, num_chunks)
-    if ctx.trace is not None:
-        # compile-time metadata only (the runner itself stays tracer-free:
-        # it may be memoized and shared with untraced contexts)
-        ctx.trace.add_span(
-            f"mux:{plan.name}", cat="compile", **mux.describe()
-        )
+        # the runner itself stays tracer-free: it may be memoized and
+        # shared with untraced contexts
+        with maybe_span(ctx.trace, "repro.mux", "compile",
+                        query=plan.name) as s:
+            mux = _make_mux(mesh, plan, impl, pack_impl, num_chunks)
+            if s is not None:
+                s.args.update(mux.describe())
     single = num_shards == 1 and num_pods == 1
     report_keys = _report_keys(plan.root)
 
@@ -450,7 +475,10 @@ def compile_plan(
         def ev(n: PNode):
             if id(n) in memo:
                 return memo[id(n)]
-            r = _eval(n)
+            for c in n.children:  # outside this node's scope
+                ev(c)
+            with jax.named_scope(_scope(n)):
+                r = _eval(n)
             memo[id(n)] = r
             return r
 
@@ -496,9 +524,10 @@ def compile_plan(
                     p[n.info["probe_key"]], p.valid,
                 )
                 cols = dict(p.columns)
-                cols.update(
-                    ops.gather_payload(b, bidx, match, list(n.info["payload"]))
-                )
+                with jax.named_scope("gather_payload"):
+                    cols.update(ops.gather_payload(
+                        b, bidx, match, list(n.info["payload"])
+                    ))
                 return Table(cols, match)
             if n.kind == "groupby_sorted":
                 t = ev(n.children[0])
@@ -569,6 +598,7 @@ def compile_plan(
     flat = []
     for name in plan.scans:
         flat.extend(_place(tables[name], num_shards, mesh, axes))
+    body.__name__ = body.__qualname__ = plan.name  # the program: jit_<plan>
     fn = shard_map(
         body,
         mesh=mesh,
@@ -637,18 +667,27 @@ class CompiledRunner(RunnerBase):
         QueryTrace)`` without touching runner state (safe under
         concurrency).  ``t_dispatch`` (a ``time.perf_counter()`` reading
         taken just before ``dispatch``) prices the trace's measured wall.
+
+        Two spans: ``repro.wait``, the host waiting for the program, then
+        ``repro.fetch``, the drop check and the device-to-host reads (one
+        ``repro.transfer`` per array, in :func:`~repro.compat.fetch`).
+        Inside a traced span they are kept by its tracer too.
         """
         from ...obs.model_check import build_query_trace
 
-        result, dropped, reports = out
-        _raise_on_dropped(self._plan.name, dropped)
-        fetched = fetch(result)
-        measured = (
-            time.perf_counter() - t_dispatch if t_dispatch is not None else None
-        )
-        qt = build_query_trace(
-            self._plan, fetch(reports), self._models, measured_s=measured
-        )
+        with maybe_span(None, "repro.wait", "execute"):
+            jax.block_until_ready(out)
+        with maybe_span(None, "repro.fetch", "execute"):
+            result, dropped, reports = out
+            _raise_on_dropped(self._plan.name, dropped)
+            fetched = fetch(result)
+            measured = (
+                time.perf_counter() - t_dispatch
+                if t_dispatch is not None else None
+            )
+            qt = build_query_trace(
+                self._plan, fetch(reports), self._models, measured_s=measured
+            )
         return fetched, qt
 
     def finalize(self, out, t_dispatch: float | None = None):

@@ -48,6 +48,7 @@ import tempfile
 from typing import Callable, Mapping
 
 from ...core.topology import ChipSpec, V5E
+from ...obs.trace import maybe_span
 from .. import stats as S
 from . import logical as L
 from .executor import compile_plan
@@ -386,7 +387,8 @@ class PlanCache:
         ``data_token`` names the table set the runner closed over — the
         caller (the serving engine: one token per engine) bumps it when the
         tables change, because a jitted closure over stale buffers would
-        silently serve old data.  Returns ``(runner, hit)``.
+        silently serve old data.  Returns ``(runner, hit)``.  A miss builds
+        the runner inside a ``repro.build`` span.
         """
         knobs = tuple(sorted(compile_kw.items())) + (
             ("mux", id(mux)) if mux is not None else (),
@@ -397,7 +399,8 @@ class PlanCache:
             self.executor_hits += 1
             return runner, True
         self.executor_misses += 1
-        runner = compile_plan(plan, tables, mux=mux, **compile_kw)
+        with maybe_span(None, "repro.build", "compile", built="executor"):
+            runner = compile_plan(plan, tables, mux=mux, **compile_kw)
         self._runners[memo_key] = runner
         return runner, False
 

@@ -658,7 +658,7 @@ def compile_plan_streamed(
             dt = _prep(pad_to(dt, morsel_cap), num_shards)
             # drain-step reports are re-offers of already-counted rows, so
             # they stay out of the per-edge arrival histograms
-            with maybe_span(tracer, f"drain-round:{rounds}", "stream",
+            with maybe_span(tracer, "repro.drain", "stream", round=rounds,
                             pending_rows=int(len(take))):
                 st, spill_out, dropped, _reports = step(
                     st, *_resident_flats(), dt.columns, dt.valid
@@ -775,7 +775,7 @@ def compile_plan_streamed(
             "prefetch_total_s": 0.0,
         }
         for p, streamed_bs, resident_bs, spill_nodes in pass_plan:
-            with maybe_span(tracer, f"pass:{p}", "stream",
+            with maybe_span(tracer, "repro.pass", "stream", pass_idx=p,
                             streamed_breakers=len(streamed_bs),
                             resident_breakers=len(resident_bs)):
                 if resident_bs:
@@ -815,8 +815,8 @@ def compile_plan_streamed(
                         break
                     wait += time.perf_counter() - w0
                     stats["morsels"] += 1
-                    with maybe_span(tracer, f"morsel:{stats['morsels']}",
-                                    "stream", pass_idx=p):
+                    with maybe_span(tracer, "repro.morsel", "stream",
+                                    morsel=stats["morsels"], pass_idx=p):
                         st, spill_out, dropped, reports = step(
                             st, *_resident_flats(), m.columns, m.valid
                         )

@@ -26,7 +26,7 @@ import dataclasses
 import time
 from typing import Callable
 
-from ...obs.trace import deposit, maybe_span
+from ...obs.trace import deposit, maybe_span, span_args
 from ..datagen import (
     LINESTATUS,
     ORDERPRIORITIES,
@@ -77,7 +77,9 @@ def run_query(pq: PlannedQuery, tables: dict, ctx=None):
     :class:`~repro.relational.source.DataSource`\\ s.  Execution is
     parameterized by one :class:`~repro.relational.context.ExecutionContext`
     (``ctx``, or None for single-shard defaults).  With ``ctx.trace`` set,
-    the run records plan/compile/execute spans and deposits the run's
+    the run keeps its ``repro.plan``/``repro.build``/``repro.execute``/
+    ``repro.finalize`` spans in memory (they reach a profiler trace either
+    way, tagged with the query's name) and deposits the run's
     :class:`~repro.obs.trace.QueryTrace` (per-edge measured vs modeled
     exchange bytes) into the tracer.
 
@@ -122,30 +124,32 @@ def run_query(pq: PlannedQuery, tables: dict, ctx=None):
         stats = ctx.planner_stats()
     catalog = {t: srcs[t].capacity for t in pq.tables}
     morsel = srcs[chunked[0]].chunk_rows if chunked else None
-    with maybe_span(tracer, f"plan:{pq.name}", "plan",
-                    num_shards=ctx.num_shards, num_pods=ctx.num_pods,
-                    streamed=bool(chunked)):
-        phys = pq.plan(
-            catalog, ctx.num_shards, num_pods=ctx.num_pods, cfg=ctx.cfg,
-            cross_pod=ctx.cross_pod, stats=stats, morsel_rows=morsel,
-        )
-    if chunked:
-        from .stream import compile_plan_streamed
+    with span_args(query=pq.name):
+        with maybe_span(tracer, "repro.plan", "plan",
+                        num_shards=ctx.num_shards, num_pods=ctx.num_pods,
+                        streamed=bool(chunked)):
+            phys = pq.plan(
+                catalog, ctx.num_shards, num_pods=ctx.num_pods, cfg=ctx.cfg,
+                cross_pod=ctx.cross_pod, stats=stats, morsel_rows=morsel,
+            )
+        if chunked:
+            from .stream import compile_plan_streamed
 
-        with maybe_span(tracer, f"compile:{pq.name}", "compile",
-                        streamed=True):
-            runner = compile_plan_streamed(phys, srcs, ctx)
-        with maybe_span(tracer, f"execute:{pq.name}", "execute"):
-            raw = runner()  # deposits its own QueryTrace + pass/morsel spans
-    else:
-        with maybe_span(tracer, f"compile:{pq.name}", "compile",
-                        streamed=False):
-            runner = compile_plan(phys, srcs, ctx)
-        t0 = time.perf_counter()
-        with maybe_span(tracer, f"execute:{pq.name}", "execute"):
-            raw, qt = runner.collect(runner.dispatch(), t_dispatch=t0)
-        deposit(tracer, qt)
-    return pq.finalize(raw) if pq.finalize else raw
+            with maybe_span(tracer, "repro.build", "compile", streamed=True):
+                runner = compile_plan_streamed(phys, srcs, ctx)
+            with maybe_span(tracer, "repro.execute", "execute"):
+                raw = runner()  # deposits its own QueryTrace, pass/morsel spans
+        else:
+            with maybe_span(tracer, "repro.build", "compile", streamed=False):
+                runner = compile_plan(phys, srcs, ctx)
+            t0 = time.perf_counter()
+            with maybe_span(tracer, "repro.execute", "execute"):
+                raw, qt = runner.collect(runner.dispatch(), t_dispatch=t0)
+            deposit(tracer, qt)
+        if not pq.finalize:
+            return raw
+        with maybe_span(tracer, "repro.finalize", "execute"):
+            return pq.finalize(raw)
 
 
 def explain_query(pq: PlannedQuery, catalog: L.Catalog, ctx=None) -> str:

@@ -400,7 +400,7 @@ class ContinuousEngine:
         batch = {"tokens": jnp.asarray(prompts)}
         if extra:
             batch.update({k: jnp.asarray(v) for k, v in extra.items()})
-        with maybe_span(self.tracer, f"prefill:len{plen}", "serve",
+        with maybe_span(self.tracer, "repro.prefill", "serve", len=plen,
                         requests=len(requests), step=step):
             logits, pref_cache = self._prefill(params, batch)
             jax.block_until_ready(logits)
@@ -518,8 +518,8 @@ class ContinuousEngine:
                 for r in admittable:
                     by_len.setdefault(r.prompt.shape[0], []).append(r)
                 if by_len:
-                    with maybe_span(self.tracer, f"admission-round:{step}",
-                                    "serve", admitted=len(admittable),
+                    with maybe_span(self.tracer, "repro.round", "serve",
+                                    round=step, admitted=len(admittable),
                                     groups=len(by_len)):
                         for plen in sorted(by_len):
                             cache = self._admit_group(
@@ -536,8 +536,8 @@ class ContinuousEngine:
 
                 # -- one fixed-shape decode step over every slot -----------
                 self.key, sub = jax.random.split(self.key)
-                with maybe_span(self.tracer, f"decode-step:{step}", "serve",
-                                live=len(self.alloc.live)):
+                with maybe_span(self.tracer, "repro.decode", "serve",
+                                step=step, live=len(self.alloc.live)):
                     logits, cache = self._decode(
                         params, jnp.asarray(self._tokens[:, None]), cache,
                         jnp.asarray(self._positions),

@@ -35,6 +35,14 @@ Execution inside one round is dispatch-then-finalize: every admitted
 query's jitted program is launched before any result is fetched, so
 compatible plans overlap on the XLA async runtime instead of serializing
 on the host.
+
+Spans (:mod:`repro.obs.trace`; on the profiler's trace always, in memory
+with ``ctx.trace``): ``repro.round`` around each admission round, and per
+request ``repro.plan`` (plan key, plan and executor caches; ``repro.build``
+inside it on a miss), ``repro.dispatch``, then ``repro.wait``,
+``repro.fetch`` (``repro.transfer`` per device-to-host read) and
+``repro.finalize``.  Each request's spans carry its ``req`` id, ``query``
+and ``tenant`` as args.
 """
 
 from __future__ import annotations
@@ -48,7 +56,7 @@ import numpy as np
 from repro.core.autotune import tune_shared_config
 from repro.core.multiplexer import make_multiplexer
 from repro.core.topology import ChipSpec, V5E
-from repro.obs.trace import QueryTrace, deposit, maybe_span
+from repro.obs.trace import QueryTrace, deposit, maybe_span, span_args
 from repro.relational import stats as rstats
 from repro.relational.context import ExecutionContext, StatsMode, require_context
 from repro.relational.planner.executor import _mesh, resolve_knobs
@@ -69,6 +77,7 @@ class QueryRequest:
     arrival_round: int = 0         # scheduling-round tick of arrival
     slo_s: float | None = None     # per-request latency SLO (None: no SLO)
     # --- filled in by the engine -------------------------------------------
+    req_id: int | None = None      # the engine's count of requests seen
     admitted_round: int | None = None
     finished_round: int | None = None
     queue_rounds: int = 0          # rounds spent arrived-but-unadmitted
@@ -93,9 +102,9 @@ class QueryServeEngine:
     multiplexer knobs, and stats mode (``StatsMode.COLLECT`` profiles the
     tables once at construction so plans are skew-aware;
     ``StatsMode.PROFILE`` uses ``ctx.stats_profile``; STATIC keeps static
-    plans).  ``ctx.trace`` attaches a tracer: every admission round and
-    request becomes a span, and each request's :class:`QueryTrace` is
-    deposited.  ``cache`` defaults to a fresh in-process
+    plans).  ``ctx.trace`` attaches a tracer: the spans of every round and
+    request (module docstring) are kept in memory too, and each request's
+    :class:`QueryTrace` is deposited.  ``cache`` defaults to a fresh in-process
     :class:`PlanCache`; hand one a ``cache_dir`` (or set
     ``REPRO_PLAN_CACHE_DIR``) and plans persist across engine processes.
     """
@@ -129,6 +138,7 @@ class QueryServeEngine:
         self.chip = chip
         self.topology = topology
         self.rounds = 0
+        self._next_req_id = 0
         self.served: list[QueryRequest] = []
         self.tenants: dict[str, dict] = {}
         self._service: dict[str, int] = {}  # fair-share counters
@@ -151,14 +161,16 @@ class QueryServeEngine:
             pq.logical, catalog, self.num_shards, num_pods=self.num_pods,
             chip=self.chip, topology=self.topology, stats=stats,
         )
-        plan, hit = self.cache.get_plan(
-            key,
-            lambda: plan_physical(
-                pq.logical, catalog, self.num_shards,
-                num_pods=self.num_pods, chip=self.chip,
-                topology=self.topology, name=pq.name, stats=stats,
-            ),
-        )
+        def build() -> PhysicalPlan:
+            with maybe_span(self.ctx.trace, "repro.build", "serve",
+                            built="plan"):
+                return plan_physical(
+                    pq.logical, catalog, self.num_shards,
+                    num_pods=self.num_pods, chip=self.chip,
+                    topology=self.topology, name=pq.name, stats=stats,
+                )
+
+        plan, hit = self.cache.get_plan(key, build)
         self._plan_stats.setdefault(key.digest, tuple(plan.shuffle_stats))
         return plan, key, hit
 
@@ -168,23 +180,24 @@ class QueryServeEngine:
         pinned knobs apply over the tuner's by the rule ``compile_plan``
         uses (:func:`~repro.relational.planner.executor.resolve_knobs`)."""
         if self._mux is None:
-            tuned = tune_shared_config(
-                self.num_shards,
-                list(self._plan_stats.values()),
-                num_pods=self.num_pods,
-                chip=self.chip,
-                topology=self.topology,
-            )
-            self.shared_tuned = tuned
             ctx = self.ctx
-            self._mux = make_multiplexer(
-                _mesh(self.num_shards, self.num_pods),
-                **resolve_knobs(tuned, ctx.impl, ctx.pack_impl, ctx.num_chunks),
-            )
-            if self.ctx.trace is not None:
-                self.ctx.trace.add_span(
-                    "mux:shared", cat="serve", **self._mux.describe()
+            with maybe_span(ctx.trace, "repro.mux", "serve") as s:
+                tuned = tune_shared_config(
+                    self.num_shards,
+                    list(self._plan_stats.values()),
+                    num_pods=self.num_pods,
+                    chip=self.chip,
+                    topology=self.topology,
                 )
+                self.shared_tuned = tuned
+                self._mux = make_multiplexer(
+                    _mesh(self.num_shards, self.num_pods),
+                    **resolve_knobs(
+                        tuned, ctx.impl, ctx.pack_impl, ctx.num_chunks
+                    ),
+                )
+                if s is not None:
+                    s.args.update(self._mux.describe())
         return self._mux
 
     def _runner(self, req: QueryRequest):
@@ -229,6 +242,9 @@ class QueryServeEngine:
         every round frees its slots: the scheduler can never deadlock, and
         the slot invariant is re-checked at each round boundary.
         """
+        for r in requests:
+            r.req_id = self._next_req_id
+            self._next_req_id += 1
         waiting = sorted(
             requests, key=lambda r: r.arrival_round
         )  # stable: preserves submission order within a tick
@@ -258,22 +274,29 @@ class QueryServeEngine:
             # is shared (memoized) across the batch, so nothing per-run is
             # ever written onto it — that was the exchange_report race.
             tracer = self.ctx.trace
-            with maybe_span(tracer, f"admission-round:{rnd}", "serve",
+            with maybe_span(tracer, "repro.round", "serve", round=rnd,
                             admitted=len(batch), queued=len(arrived)):
                 launched = []
                 for slot, r in batch:
-                    runner = self._runner(r)
-                    t0 = time.perf_counter()
-                    launched.append((slot, r, runner, runner.dispatch(), t0))
+                    with span_args(req=r.req_id, query=r.query.name,
+                                   tenant=r.tenant):
+                        with maybe_span(tracer, "repro.plan", "serve"):
+                            runner = self._runner(r)
+                        t0 = time.perf_counter()
+                        with maybe_span(tracer, "repro.dispatch", "serve"):
+                            out = runner.dispatch()
+                    launched.append((slot, r, runner, out, t0))
                 for slot, r, runner, out, t0 in launched:
-                    with maybe_span(tracer, f"request:{r.query.name}",
-                                    "serve", tenant=r.tenant):
+                    with span_args(req=r.req_id, query=r.query.name,
+                                   tenant=r.tenant):
                         raw, qt = runner.collect(out, t_dispatch=t0)
-                    r.trace = qt
-                    deposit(tracer, qt)
-                    r.result = (
-                        r.query.finalize(raw) if r.query.finalize else raw
-                    )
+                        r.trace = qt
+                        deposit(tracer, qt)
+                        with maybe_span(tracer, "repro.finalize", "serve"):
+                            r.result = (
+                                r.query.finalize(raw)
+                                if r.query.finalize else raw
+                            )
                     r.ttfr_s = time.perf_counter() - r._t_arrive
                     r.finished_round = rnd
                     self.alloc.release(slot)

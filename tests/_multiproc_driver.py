@@ -368,16 +368,20 @@ def scenario_trace_merge():
         )
         pids = {e["pid"] for e in merged["traceEvents"]}
         assert pids == set(range(INFO.num_processes)), pids
-        # every process contributed its exchange spans and byte counters
+        # every process contributed its execute span, its q17 QueryTrace
+        # record with measured exchange edges, and its byte counters
         per_pid_names = {
             pid: {e["name"] for e in merged["traceEvents"]
                   if e["pid"] == pid and e["ph"] == "B"}
             for pid in pids
         }
         for pid, names in per_pid_names.items():
-            assert any(nm.startswith("exchange:") for nm in names), (
-                pid, names)
-        assert merged["counters"]["exchange.measured_bytes"] > 0
+            assert "repro.execute" in names, (pid, names)
+        records = merged["queryTraces"]
+        assert [r["query"] for r in records] == ["q17"] * INFO.num_processes
+        assert all(r["edges"] for r in records), records
+        shipped = sum(e["measured_bytes"] for r in records for e in r["edges"])
+        assert merged["counters"]["exchange.measured_bytes"] == shipped > 0
         with open(os.path.join(trace_dir, "merged.json")) as f:
             json.load(f)  # Perfetto-loadable JSON on disk
     multihost_utils.sync_global_devices("merge-checked")

@@ -34,6 +34,7 @@ from repro.obs.trace import (
     deposit,
     maybe_span,
     model_error,
+    span_args,
 )
 
 
@@ -92,23 +93,40 @@ def test_model_report_worst_edge():
 
 def test_spans_nest_and_close():
     tr = Tracer(pid=0)
-    with tr.span("plan:q17", cat="plan"):
-        with tr.span("compile:q17", cat="compile", streamed=True):
+    with tr.span("repro.plan", cat="plan", query="q17"):
+        with tr.span("repro.build", cat="compile", streamed=True):
             pass
-        with tr.span("execute:q17", cat="execute"):
-            tr.add_span("exchange:e0", cat="exchange", measured_bytes=42)
+        with tr.span("repro.execute", cat="execute"):
+            with maybe_span(tr, "repro.fetch", "execute", bytes=42):
+                pass
     assert len(tr.spans) == 1  # one root
     root = tr.spans[0]
     assert [s.name for s in root.walk()] == [
-        "plan:q17", "compile:q17", "execute:q17", "exchange:e0"
+        "repro.plan", "repro.build", "repro.execute", "repro.fetch"
     ]
     assert all(s.dur is not None for s in root.walk())
     assert root.children[0].args == {"streamed": True}
+    assert root.children[1].children[0].args == {"bytes": 42}
 
 
 def test_maybe_span_is_noop_without_tracer():
     with maybe_span(None, "anything") as s:
         assert s is None
+
+
+def test_span_args_reach_nested_spans():
+    tr = Tracer(pid=0)
+    with span_args(req=7, query="q1"):
+        with tr.span("repro.plan", query="q6"):  # an explicit arg wins
+            with span_args(tenant="a"):
+                with tr.span("repro.fetch"):
+                    pass
+    with tr.span("repro.round"):
+        pass
+    plan, rnd = tr.spans
+    assert plan.args == {"req": 7, "query": "q6"}
+    assert plan.children[0].args == {"req": 7, "query": "q1", "tenant": "a"}
+    assert rnd.args == {}
 
 
 def test_spans_from_threads_do_not_interleave():
@@ -147,19 +165,19 @@ def test_counters_gauges_histograms():
 
 
 # ---------------------------------------------------------------------------
-# deposit: QueryTrace -> tracer spans + counters.
+# deposit: QueryTrace -> tracer record + counters.
 # ---------------------------------------------------------------------------
 
 def test_deposit_lays_out_edges_and_counters():
     tr = Tracer(pid=0)
-    qt = _qt(_edge(key="a"), _edge(key="b"))
+    qt = _qt(_edge(key="a"), _edge(key="b", measured=300, modeled=400))
     deposit(tr, qt)
+    # the record keeps every edge; no span is made up for it
     assert tr.query_traces == [qt]
-    names = [s.name for s in tr.spans]
-    assert names == ["exchange:a", "exchange:b"]
-    # edge spans partition the measured window by predicted share
-    assert sum(s.dur for s in tr.spans) == pytest.approx(0.5)
-    assert tr.counters["exchange.measured_bytes"] == 1800.0
+    assert [e.key for e in tr.query_traces[0].edges] == ["a", "b"]
+    assert tr.spans == []
+    assert tr.counters["exchange.measured_bytes"] == 1200.0
+    assert tr.counters["exchange.modeled_wire_bytes"] == 1400.0
     assert tr.counters["query.q17.runs"] == 1.0
     assert tr.counters["query.q17.morsels"] == 4.0
     deposit(None, qt)  # no-op without a tracer
@@ -189,8 +207,8 @@ def test_query_trace_roundtrip_defaults_traversals():
 
 def _traced_tracer() -> Tracer:
     tr = Tracer(pid=0)
-    with tr.span("plan:q17", cat="plan"):
-        with tr.span("compile:q17", cat="compile"):
+    with tr.span("repro.plan", cat="plan", query="q17"):
+        with tr.span("repro.build", cat="compile"):
             pass
     deposit(tr, _qt(_edge(key="a"), _edge(key="b")))
     return tr
