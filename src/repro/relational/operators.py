@@ -58,17 +58,25 @@ def groupby_dense(
     *pre-aggregation* building block (Fig 6c): each device reduces its rows
     locally into num_groups cells; cross-device combination is a psum of the
     tiny group table instead of a shuffle of raw rows.
+
+    Up to ``DENSE_COMPARE_MAX_GROUPS`` groups the rows are compared with
+    every group id and reduced (scope ``compare``); above it they are
+    scatter-added (scope ``scatter``).  Sums are float32, counts int32.
     """
     gid = jnp.where(valid, group_ids, num_groups)  # invalid -> overflow cell
+    if num_groups <= DENSE_COMPARE_MAX_GROUPS:
+        with jax.named_scope("compare"):
+            return _compare_reduce(gid, num_groups, aggregates)
     out = {}
-    for name, (col, kind) in aggregates.items():
-        if kind == "sum":
-            vals = col.astype(jnp.float32)
-        else:  # count
-            vals = jnp.ones_like(gid, jnp.int32)
-        out[name] = _blocked_segment_sum(
-            jnp.where(valid, vals, 0), gid, num_groups + 1
-        )[:num_groups]
+    with jax.named_scope("scatter"):
+        for name, (col, kind) in aggregates.items():
+            if kind == "sum":
+                vals = col.astype(jnp.float32)
+            else:  # count
+                vals = jnp.ones_like(gid, jnp.int32)
+            out[name] = _blocked_segment_sum(
+                jnp.where(valid, vals, 0), gid, num_groups + 1
+            )[:num_groups]
     return out
 
 
@@ -77,6 +85,40 @@ def groupby_dense(
 # Q1 group) that drifts past 1e-4 relative.  Per-block partials, combined
 # by one reduction, keep every accumulator to a few thousand rows.
 SUM_BLOCK = 4096
+
+# Largest group domain reduced by compare-and-reduce.  A scatter-add runs
+# nearly serially on the TPU (about 6.6 ns a row on a v5e), while comparing
+# every row with every group costs rows x groups vector selects: at 256
+# groups, about 6M x 256 x 3 vector ops an aggregate at SF 1, still under
+# the scatter's time.  Beyond that the compare's cost keeps growing with the
+# domain and the scatter's does not.
+DENSE_COMPARE_MAX_GROUPS = 256
+
+
+def _blocks(x: jax.Array, fill) -> jax.Array:
+    """``x`` padded with ``fill`` to whole blocks, as ``[blocks, SUM_BLOCK]``."""
+    pad = (-x.shape[0]) % SUM_BLOCK
+    return jnp.pad(x, (0, pad), constant_values=fill).reshape(-1, SUM_BLOCK)
+
+
+def _compare_reduce(
+    gid: jax.Array, num_groups: int, aggregates: dict[str, tuple[jax.Array, str]]
+) -> dict[str, jax.Array]:
+    """Per-block partials of every aggregate from one comparison of the
+    rows' group ids with each group (no scatter, no matmul), then a
+    reduction over the blocks.  Rows in the overflow cell match no group.
+    XLA fuses the sums into one multi-output reduction that reads each
+    column once and never materializes the ``[groups, rows]`` comparison."""
+    hit = _blocks(gid, num_groups)[None] == jnp.arange(num_groups)[:, None, None]
+    count = hit.sum(axis=-1, dtype=jnp.int32).sum(axis=-1)  # dead code if unused
+    out = {}
+    for name, (col, kind) in aggregates.items():
+        if kind == "sum":
+            vals = _blocks(col.astype(jnp.float32), 0)[None]
+            out[name] = jnp.where(hit, vals, 0.0).sum(axis=-1).sum(axis=-1)
+        else:  # count
+            out[name] = count
+    return out
 
 
 def _blocked_segment_sum(

@@ -120,3 +120,45 @@ def test_q3_executor_compiles_on_four_chips(topo, monkeypatch):
         + mem.temp_size_in_bytes - mem.alias_size_in_bytes
     )
     assert 0 < per_device < HBM_BYTES, per_device
+
+
+# SF 1 lineitem on one chip, and the columns Q1 reads.
+LINEITEM_SF1 = 6_000_000
+Q1_COLUMNS = (
+    "l_shipdate", "l_returnflag", "l_linestatus", "l_extendedprice",
+    "l_discount", "l_tax", "l_quantity",
+)
+
+
+def _has_scatter(compiled) -> bool:
+    # The instruction, not the word: ``scatter`` also names source frames.
+    return "scatter(" in compiled.as_text()
+
+
+def test_q1_dense_groupby_compiles_without_scatter(one_chip):
+    """Q1's six groups take the compare-and-reduce path: no scatter-add,
+    and no matmul (the MXU would round the sums' operands to bfloat16)."""
+    from repro.relational import queries
+    from repro.relational.table import Table
+
+    col = jax.ShapeDtypeStruct((LINEITEM_SF1,), jnp.int32, sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((LINEITEM_SF1,), jnp.bool_, sharding=one_chip)
+    compiled = jax.jit(
+        lambda cols, v: queries.q1_local(Table(dict(zip(Q1_COLUMNS, cols)), v))
+    ).lower([col] * len(Q1_COLUMNS), valid).compile()
+    assert not _has_scatter(compiled)
+    assert not any(op in compiled.as_text() for op in (" dot(", " convolution("))
+
+
+def test_dense_groupby_above_compare_limit_scatters(one_chip):
+    from repro.relational import operators as ops
+
+    num_groups = ops.DENSE_COMPARE_MAX_GROUPS + 1
+    gid = jax.ShapeDtypeStruct((LINEITEM_SF1,), jnp.int32, sharding=one_chip)
+    valid = jax.ShapeDtypeStruct((LINEITEM_SF1,), jnp.bool_, sharding=one_chip)
+    compiled = jax.jit(
+        lambda g, v: ops.groupby_dense(
+            g, num_groups, {"s": (g, "sum"), "n": (g, "count")}, v
+        )
+    ).lower(gid, valid).compile()
+    assert _has_scatter(compiled)

@@ -153,3 +153,18 @@ def test_q3_program_and_ops_are_named():
     assert {"join_pk", "groupby_sorted", "topk"} <= scopes, scopes
     assert scopes <= {"scan", "filter", "project", "join_pk", "groupby_sorted",
                       "topk", "shuffle", "broadcast"}, scopes
+
+
+def test_q1_dense_groupby_scope_names_its_path():
+    """Q1's six groups reduce by compare-and-reduce, and the ops say so."""
+    tabs = datagen.gen_all(SF)
+    pq = tpch.q1()
+    tables = {t: tabs[t] for t in pq.tables}
+    plan = pq.plan({t: tables[t].capacity for t in pq.tables}, 1)
+    run = compile_plan(plan, tables)
+    hlo = run._jfn.lower(*run._flat).compile().as_text()
+    paths = {
+        m.split("/")[2]
+        for m in re.findall(r'op_name="(jit\(q1\)/groupby_dense/[^"]*)"', hlo)
+    }
+    assert "compare" in paths and "scatter" not in paths, paths
