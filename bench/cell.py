@@ -2,7 +2,8 @@
 
 A cell names a configuration and a traffic mix; each lives in a JSON file of
 its own (``configs/<config>.json``, ``traffic/<traffic>.json``), and each
-per-layer metric in ``metrics/<name>.py``.  Adding a cell, a mix or a metric
+per-layer metric in ``metrics/<name>.py`` (or ``metrics/<reader>.py`` for a
+name ``<reader>.<part>``).  Adding a cell, a mix or a metric
 is adding files: nothing here names one.  The readers refuse unknown and
 missing keys, so a typo fails instead of quietly changing what runs.
 """
@@ -73,8 +74,13 @@ def load_traffic(name: str, root: Path = HERE) -> dict:
 
 
 def load_metric(name: str, root: Path = HERE):
-    """The ``read(run)`` function of ``metrics/<name>.py``."""
+    """The ``read(run)`` function of ``metrics/<name>.py``.  A name
+    ``<reader>.<part>`` with no file of its own is read by
+    ``metrics/<reader>.py``: one quantity, listed once for each end-to-end
+    metric it moves, as ``fetch_ms_per_query.host`` in the host-bound cells."""
     path = root / "metrics" / f"{name}.py"
+    if not path.exists():
+        path = root / "metrics" / f"{name.split('.')[0]}.py"
     spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)

@@ -65,9 +65,12 @@ def set_jax_env() -> None:
 
 
 def tpu_devices(chips: int) -> list:
+    t0 = time.perf_counter()
     import jax
 
+    t1 = time.perf_counter()
     devices = jax.devices()
+    log(f"jax import {t1 - t0} s, devices {time.perf_counter() - t1} s")
     if devices[0].platform != "tpu":
         raise NoChip(f"JAX found no TPU (platform {devices[0].platform!r})")
     if len(devices) < chips:
@@ -83,6 +86,7 @@ class CompileCounter:
         import jax
 
         self.hits = self.misses = self.builds = 0
+        self.seconds: dict[str, float] = {}  # duration event -> total seconds
         jax.monitoring.register_event_listener(self._event)
         jax.monitoring.register_event_duration_secs_listener(self._duration)
 
@@ -95,6 +99,21 @@ class CompileCounter:
     def _duration(self, event, duration, **_):
         if event == COMPILE_EVENT:
             self.builds += 1
+        self.seconds[event] = self.seconds.get(event, 0.0) + duration
+
+
+class Phases:
+    """Set-up's phases on the host clock: ``phases(name)`` ends the phase
+    ``name``, which began where the last one ended (the first at ``T0``)."""
+
+    def __init__(self):
+        self.last = T0
+        self.seconds: dict[str, float] = {}
+
+    def __call__(self, name: str) -> None:
+        now = time.perf_counter()
+        self.seconds[name] = now - self.last
+        self.last = now
 
 
 class Clock:
@@ -135,6 +154,7 @@ class Window:
     traced: dict  # template -> queries completed in the traced rounds
     compiles: int  # executables built inside the window
     round_s: list = dataclasses.field(default_factory=list)  # each round's wall time
+    gc_collections: list = dataclasses.field(default_factory=list)  # per generation
     error: str | None = None
 
 
@@ -148,6 +168,7 @@ def run_window(engine, templates, streams, seconds, clock, counter, trace_rounds
     n = len(templates)
     w = Window([], [], 0, 0, 0.0, 0, {}, 0)
     builds0 = counter.builds
+    gc0 = [g["collections"] for g in gc.get_stats()]
     tracing = False
     t0 = time.perf_counter()
     rnd = 0
@@ -184,6 +205,7 @@ def run_window(engine, templates, streams, seconds, clock, counter, trace_rounds
                 tracing = False
     w.close_s = time.perf_counter() - t0
     w.compiles = counter.builds - builds0
+    w.gc_collections = [g["collections"] - c for g, c in zip(gc.get_stats(), gc0)]
     if tracing:
         jax.profiler.stop_trace()
     return w
@@ -192,7 +214,7 @@ def run_window(engine, templates, streams, seconds, clock, counter, trace_rounds
 def end_to_end(name: str, w: Window, setup_s: float) -> float:
     if name == "qps":
         return len(w.latencies) / w.close_s
-    if name == "ttfr_p50_s":
+    if name in ("ttfr_p50_s", "ttfr_p50_host_s"):  # one quantity, bounded per cell kind
         return percentile(w.latencies, 50)
     if name == "ttfr_p95_s":
         return percentile(w.latencies, 95)
@@ -217,6 +239,8 @@ class LayerView:
 
 def run_cell(cell, seed: int, seconds: float, trace: bool, devices) -> dict:
     """One run of ``cell`` on ``devices``; returns the result object."""
+    phases = Phases()
+    phases("jax_start")  # interpreter, JAX and the chip
     import compare
     import reference
     import tpch_data
@@ -226,6 +250,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices) -> dict:
     from repro.relational.table import from_numpy
     from repro.serve import QueryRequest, QueryServeEngine
 
+    phases("imports")
     cfg, mix = cell.config, cell.traffic
     log(f"cell {cell.name}: SF {cfg['scale_factor']} on {cell.chips} chip(s), "
         f"templates {mix['templates']}, {mix['streams']} streams, seed {seed}")
@@ -233,8 +258,11 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices) -> dict:
     counter = CompileCounter()
     names = list(dict.fromkeys(mix["templates"]))
     needed = sorted({t for n in names for t in reference.TABLES[n]})
+    phases("compile_cache")
     data = tpch_data.generate(cfg["scale_factor"], seed, needed)
+    phases("datagen")
     tables = {t: from_numpy(cols) for t, cols in data.items()}
+    phases("placement")
     ctx = ExecutionContext(**cfg["mesh"])
     clock = Clock()
     by_name = {n: clock.wrap(tpch.ALL_QUERIES[n]()) for n in names}
@@ -242,26 +270,34 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices) -> dict:
     engine = QueryServeEngine(
         tables, ctx, num_slots=mix["streams"], templates=list(by_name.values())
     )
+    phases("engine")
     answers = []
     for n, pq in by_name.items():
         (req,) = engine.serve([QueryRequest(tenant="warmup", query=pq)])
         clock.ready.pop(id(req.result))
         answers.append((n, req.result))
+    phases("warmup")
     import kernels
 
     pack_calls = kernels.pack_calls(engine, by_name.values())
+    phases("pack_calls")
     setup_s = time.perf_counter() - T0
     log(f"setup_s={setup_s} persistent_cache_hits={counter.hits} "
         f"persistent_cache_misses={counter.misses} executables_built={counter.builds}")
+    log(f"setup phases s: {phases.seconds} compile events s: {counter.seconds}")
 
     with tempfile.TemporaryDirectory(prefix="bench-trace-") as trace_dir:
         w = run_window(
             engine, templates, mix["streams"], seconds, clock, counter,
             mix["trace_rounds"], trace_dir if trace else None,
         )
+        thirds = [w.round_s[i * len(w.round_s) // 3:(i + 1) * len(w.round_s) // 3]
+                  for i in range(3)]
         log(f"window: rounds={w.attempted // mix['streams']} completed={len(w.latencies)} "
             f"close_s={w.close_s} between_rounds_s={w.close_s - sum(w.round_s)} "
             f"longest_rounds_s={sorted(w.round_s)[-3:]} "
+            f"median_round_s_by_third={[percentile(t, 50) for t in thirds if t]} "
+            f"gc_collections={w.gc_collections} "
             f"compiles_in_window={w.compiles} error={w.error}")
         used = devices[: cell.chips]
         stats = [d.memory_stats() or {} for d in used]

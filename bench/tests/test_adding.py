@@ -32,6 +32,8 @@ def add_cell(root):
         "def read(view):\n    return float(view.queries) or None\n"
     )
     bench = json.loads((root.parent / "BENCHMARK.json").read_text())
+    p50 = next(m for m in bench["end_to_end"] if m["name"] == "ttfr_p50_s")
+    p50["workloads"].append("tpch-tiny.mixed")
     bench["workloads"].append({"name": "tpch-tiny.mixed", "config": "tpch-tiny",
                                "traffic": "mixed", "chips": 1, "why": "test"})
     bench["per_layer"].append({"name": "queries_traced", "unit": "queries", "better": "higher",
@@ -59,7 +61,8 @@ def test_listed_cells_resolve():
     for wl in (w["name"] for w in listed):
         c = cells.resolve(wl)
         assert c.chips == c.config["chips"]
-        assert {"ttfr_p50_s", "setup_s"} <= {m["name"] for m in c.end_to_end}
+        reported = {m["name"] for m in c.end_to_end}
+        assert "setup_s" in reported and len(reported) >= 2 and c.per_layer
         for m in c.per_layer:
             assert callable(cells.load_metric(m["name"]))
 
@@ -89,3 +92,22 @@ def test_tiny_join_cell_runs_correct_on_cpu():
                        jax.devices())
     assert res["correct"] and res["failed"] == 0
     assert res["checks"]["wrong"]["value"] == 0
+
+
+def test_benchmark_json_metrics_fit_its_cells():
+    """Every cell a metric lists is listed; each cell's end-to-end metrics
+    are computed by ``run.end_to_end`` and are distinct quantities; each of
+    its per-layer metrics moves an end-to-end metric the cell reports."""
+    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    listed = {w["name"] for w in bench["workloads"]}
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        assert set(m.get("workloads", listed)) <= listed, m["name"]
+    w = run.Window(answers=[], latencies=[0.001 * i for i in range(1, 101)], attempted=100,
+                   failed=0, close_s=7.0, traced_rounds=0, traced={}, compiles=0)
+    for name in sorted(listed):
+        c = cells.resolve(name)
+        values = {m["name"]: run.end_to_end(m["name"], w, setup_s=11.0) for m in c.end_to_end}
+        same = [(a, b) for a in values for b in values if a < b and values[a] == values[b]]
+        assert not same, f"{name} reports one quantity twice: {same}"
+        for m in c.per_layer:
+            assert m["moves"] in values, (name, m["name"])
