@@ -55,7 +55,10 @@ error, never silent row loss.
 The hash shuffles name their phases on the device trace: each op runs
 under a ``jax.named_scope`` of ``hash`` (destination of each row),
 ``pack`` (rank and scatter into message buffers; the fused Pallas kernel
-hashes here too), ``ship`` (the transport) or ``unpack`` (reassembly).
+hashes here too), ``ship`` (the transport) or ``unpack`` (reassembly).  On a
+two-level mesh those scopes nest inside ``cross_pod`` (the hop over the pod
+axis) or ``in_pod`` (the hop inside a pod), and so do the phases of the
+two-level broadcast and psum.
 
 Everything here must be called inside ``shard_map`` (a named mesh axis in
 scope).  The pjit/auto-sharded layers above call these through
@@ -270,12 +273,18 @@ def hierarchical_psum(x: jax.Array, inner_axis: str, outer_axis: str) -> jax.Arr
     (DCI).  Cross-pod traffic drops by the inner axis size versus a flat
     all-reduce over both axes.
 
+    The inner phases run under an ``in_pod`` scope on the device trace, the
+    outer one under ``cross_pod``.
+
     ``x``'s leading dim must be divisible by the inner axis size (use
     :func:`hierarchical_psum_tree` for arbitrary pytrees).
     """
-    shard = lax.psum_scatter(x, inner_axis, scatter_dimension=0, tiled=True)
-    shard = lax.psum(shard, outer_axis)
-    return lax.all_gather(shard, inner_axis, axis=0, tiled=True)
+    with jax.named_scope("in_pod"):
+        shard = lax.psum_scatter(x, inner_axis, scatter_dimension=0, tiled=True)
+    with jax.named_scope("cross_pod"):
+        shard = lax.psum(shard, outer_axis)
+    with jax.named_scope("in_pod"):
+        return lax.all_gather(shard, inner_axis, axis=0, tiled=True)
 
 
 def _pad_to(x: jax.Array, multiple: int) -> jax.Array:
@@ -608,7 +617,9 @@ def hash_shuffle_two_level(
     ``[n * P * capacity]`` rows per device — the same total as a flat
     ``N``-unit shuffle with that capacity.  Hop 1 is structurally zero-drop
     (its per-pod message capacity is the full local row count); hop 2
-    inherits the caller's bound scaled by ``P``.  ``num_chunks`` /
+    inherits the caller's bound scaled by ``P``.  On the device trace hop 1
+    runs under a ``cross_pod`` scope and hop 2 under ``in_pod``, each with
+    its own ``hash``/``pack``/``ship``/``unpack`` inside.  ``num_chunks`` /
     ``transport_chunks`` pipeline the in-pod hop (the coarse hop is a single
     phase sequence and ships unchunked).  The returned ``dropped`` is
     psummed over BOTH axes — a global count.
@@ -627,61 +638,63 @@ def hash_shuffle_two_level(
     if valid is None:
         valid = jnp.ones((T,), jnp.bool_)
 
-    # Hop 1: pack by destination pod, one rank computation for keys + rows.
-    with jax.named_scope("hash"):
-        gdest = (fibonacci_hash(keys) % jnp.uint32(N)).astype(jnp.int32)
-        dest_pod = jnp.where(valid, gdest // n, P)  # invalid -> overflow bucket
-    with jax.named_scope("pack"):
-        my_rank, counts_all = _rank_by_destination(dest_pod, P, pack_impl)
-    # Coarse shift phases over the pod axis (the multiplexer connections of
-    # the paper): scheduled transports use the shift schedule — valid for
-    # every P, unlike one_factorization — and "xla" keeps the monolithic
-    # all-to-all for the baseline configuration.
-    hop1 = "xla" if impl == "xla" else "round_robin"
-    if rows.ndim == 2 and rows.dtype == keys.dtype:
-        # Ship keys as an extra leading column of the row matrix: one phase
-        # sequence over the slowest network instead of two.  (This is the
-        # relational hot path — int32 keys, packed int32 rows.)
+    with jax.named_scope("cross_pod"):
+        # Hop 1: pack by destination pod, one rank computation for keys + rows.
+        with jax.named_scope("hash"):
+            gdest = (fibonacci_hash(keys) % jnp.uint32(N)).astype(jnp.int32)
+            dest_pod = jnp.where(valid, gdest // n, P)  # invalid -> overflow bucket
         with jax.named_scope("pack"):
-            aug = jnp.concatenate([keys[:, None], rows], axis=1)
-            aug_bufs, counts, drop1 = _scatter_pack(
-                dest_pod, my_rank, counts_all, aug, P, T, valid
-            )
+            my_rank, counts_all = _rank_by_destination(dest_pod, P, pack_impl)
+        # Coarse shift phases over the pod axis (the multiplexer connections of
+        # the paper): scheduled transports use the shift schedule — valid for
+        # every P, unlike one_factorization — and "xla" keeps the monolithic
+        # all-to-all for the baseline configuration.
+        hop1 = "xla" if impl == "xla" else "round_robin"
+        if rows.ndim == 2 and rows.dtype == keys.dtype:
+            # Ship keys as an extra leading column of the row matrix: one phase
+            # sequence over the slowest network instead of two.  (This is the
+            # relational hot path — int32 keys, packed int32 rows.)
+            with jax.named_scope("pack"):
+                aug = jnp.concatenate([keys[:, None], rows], axis=1)
+                aug_bufs, counts, drop1 = _scatter_pack(
+                    dest_pod, my_rank, counts_all, aug, P, T, valid
+                )
+            with jax.named_scope("ship"):
+                aug_in = all_to_all(aug_bufs, outer_axis, impl=hop1)
+            with jax.named_scope("unpack"):
+                keys_in, rows_in = aug_in[:, :, 0], aug_in[:, :, 1:]
+        else:
+            with jax.named_scope("pack"):
+                key_bufs, counts, drop1 = _scatter_pack(
+                    dest_pod, my_rank, counts_all, keys, P, T, valid
+                )
+                row_bufs, _, _ = _scatter_pack(
+                    dest_pod, my_rank, counts_all, rows, P, T, valid
+                )
+            with jax.named_scope("ship"):
+                keys_in = all_to_all(key_bufs, outer_axis, impl=hop1)
+                rows_in = all_to_all(row_bufs, outer_axis, impl=hop1)
         with jax.named_scope("ship"):
-            aug_in = all_to_all(aug_bufs, outer_axis, impl=hop1)
+            counts_in = all_to_all(counts.reshape(P, 1), outer_axis, impl=hop1)
         with jax.named_scope("unpack"):
-            keys_in, rows_in = aug_in[:, :, 0], aug_in[:, :, 1:]
-    else:
-        with jax.named_scope("pack"):
-            key_bufs, counts, drop1 = _scatter_pack(
-                dest_pod, my_rank, counts_all, keys, P, T, valid
-            )
-            row_bufs, _, _ = _scatter_pack(
-                dest_pod, my_rank, counts_all, rows, P, T, valid
-            )
-        with jax.named_scope("ship"):
-            keys_in = all_to_all(key_bufs, outer_axis, impl=hop1)
-            rows_in = all_to_all(row_bufs, outer_axis, impl=hop1)
-    with jax.named_scope("ship"):
-        counts_in = all_to_all(counts.reshape(P, 1), outer_axis, impl=hop1)
-    with jax.named_scope("unpack"):
-        valid_in = (
-            jnp.arange(T)[None, :] < counts_in.reshape(P)[:, None]
-        ).reshape(P * T)
+            valid_in = (
+                jnp.arange(T)[None, :] < counts_in.reshape(P)[:, None]
+            ).reshape(P * T)
 
-    # Hop 2: ordinary in-pod shuffle.  n | N makes hash % n the correct
-    # in-pod owner for rows from any source pod.
-    out_rows, out_valid, drop2 = hash_shuffle(
-        keys_in.reshape(P * T),
-        rows_in.reshape((P * T,) + rows_in.shape[2:]),
-        inner_axis,
-        capacity * P,
-        impl=impl,
-        valid=valid_in,
-        pack_impl=pack_impl,
-        num_chunks=num_chunks,
-        transport_chunks=transport_chunks,
-    )
+    with jax.named_scope("in_pod"):
+        # Hop 2: ordinary in-pod shuffle.  n | N makes hash % n the correct
+        # in-pod owner for rows from any source pod.
+        out_rows, out_valid, drop2 = hash_shuffle(
+            keys_in.reshape(P * T),
+            rows_in.reshape((P * T,) + rows_in.shape[2:]),
+            inner_axis,
+            capacity * P,
+            impl=impl,
+            valid=valid_in,
+            pack_impl=pack_impl,
+            num_chunks=num_chunks,
+            transport_chunks=transport_chunks,
+        )
     # drop2 is already psummed over the inner axis; lift both to global.
     dropped = lax.psum(lax.psum(drop1, inner_axis), outer_axis)
     dropped = dropped + lax.psum(drop2, outer_axis)

@@ -303,13 +303,16 @@ class CommMultiplexer:
         crosses DCI once per remote pod, at pod granularity.  Result leading
         dims are ``[num_pods, n]`` on a two-level mesh, ``[n]`` otherwise
         (callers flatten; every device holds an identical copy either way).
+        The two hops run under ``in_pod`` and ``cross_pod`` scopes.
         """
-        y = self.broadcast(x, axis_name)
         pod = self.plan.pod_axis
         if pod is None:
-            return y
+            return self.broadcast(x, axis_name)
+        with jax.named_scope("in_pod"):
+            y = self.broadcast(x, axis_name)
         impl = "xla" if self.impl == "xla" else "ring"
-        return exchange.broadcast_exchange(y, pod, impl=impl)
+        with jax.named_scope("cross_pod"):
+            return exchange.broadcast_exchange(y, pod, impl=impl)
 
     # -- gradient sync (hybrid two-level vs flat) ---------------------------
 

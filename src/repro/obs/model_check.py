@@ -129,7 +129,9 @@ def build_query_trace(
     reports plus the plan's edge models.
 
     ``reports`` maps edge keys to the executor's per-shuffle report
-    (``hist``/``overload``/``plain_overload``/``salted``).  Streamed runs
+    (``counts``/``overload``/``plain_overload``/``salted``; ``counts`` is
+    the arrival histogram then the cross-pod and in-pod row counts, see
+    ``executor._shuffle_histogram``).  Streamed runs
     key multi-pass traversals as ``<edge>@p<pass>`` — the base edge's
     model applies to each traversal (every pass re-ships the rows).
     ``measured_s`` (dispatch-to-fetched wall) is apportioned over edges by
@@ -148,7 +150,9 @@ def build_query_trace(
     for (key, rep), pred in zip(reports.items(), preds):
         base = key.split("@p")[0]
         m = models.get(base) or {}
-        hist = np.asarray(rep["hist"]).astype(np.int64)
+        counts = np.asarray(rep["counts"]).astype(np.int64)
+        hist = counts[:plan.num_shards]
+        crosspod_rows, inpod_rows = counts[plan.num_shards:]
         rows_arrived = int(hist.sum())
         row_bytes = int(m.get("row_bytes") or 0)
         traversals = int(rep.get("traversals", 1) or 1)
@@ -162,6 +166,8 @@ def build_query_trace(
                 row_bytes=row_bytes,
                 hist=tuple(int(x) for x in hist),
                 measured_bytes=int(rows_arrived * row_bytes * frac),
+                crosspod_bytes=int(crosspod_rows) * row_bytes,
+                inpod_bytes=int(inpod_rows) * row_bytes,
                 modeled_wire_bytes=(
                     int(m.get("modeled_wire_bytes") or 0) * traversals
                 ),

@@ -250,6 +250,12 @@ class ExchangeEdge:
     stats under the plan's tuned knobs; ``measured_s`` the edge's share of
     the run's measured wall time (apportioned by predicted share — per-edge
     device timestamps need a profiler, not a counter).
+
+    ``crosspod_bytes`` and ``inpod_bytes`` split the traffic by network
+    level, counted exactly on the devices from the exchange's routing rule:
+    the valid rows whose destination pod differs from the pod they start in
+    (0 on a single-pod mesh), and the rows the in-pod hop moves to another
+    chip of the pod they are in, each times ``row_bytes``.
     """
 
     key: str
@@ -270,6 +276,8 @@ class ExchangeEdge:
     # re-ships the unchanged table every step).  ``modeled_wire_bytes``
     # already includes the multiplier — the byte model prices one shipment.
     traversals: int = 1
+    crosspod_bytes: int = 0      # rows that change pod x row_bytes
+    inpod_bytes: int = 0         # rows that change chip inside a pod x row_bytes
 
     @property
     def byte_model_err(self) -> float | None:
@@ -328,13 +336,17 @@ class QueryTrace:
 
 def deposit(tracer: Tracer | None, qt: QueryTrace) -> None:
     """File one run's QueryTrace in a tracer: the record itself and its
-    byte and run counters.  No-op without a tracer."""
+    byte counters (``exchange.measured_bytes``, ``.modeled_wire_bytes``,
+    ``.crosspod_bytes``, ``.inpod_bytes``) and run counters.  No-op without
+    a tracer."""
     if tracer is None:
         return
     tracer.add_query_trace(qt)
     for e in qt.edges:
         tracer.counter("exchange.measured_bytes", e.measured_bytes)
         tracer.counter("exchange.modeled_wire_bytes", e.modeled_wire_bytes)
+        tracer.counter("exchange.crosspod_bytes", e.crosspod_bytes)
+        tracer.counter("exchange.inpod_bytes", e.inpod_bytes)
     tracer.counter(f"query.{qt.query}.runs", 1.0)
     for k, v in qt.counters.items():
         if isinstance(v, (int, float)):

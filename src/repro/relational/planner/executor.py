@@ -175,16 +175,22 @@ def _exchange_by_key(
 
 
 def _shuffle_histogram(
-    keys: jax.Array, valid: jax.Array, num_shards: int, axes
+    keys: jax.Array, valid: jax.Array, num_shards: int, num_pods: int, axes
 ) -> tuple[jax.Array, jax.Array]:
-    """Global per-destination row histogram of a (routing-key, valid) pair.
+    """Global arrival counts of a (routing-key, valid) pair.
 
     Uses the exact routing rule of the exchange (``fibonacci_hash % N``
     over the GLOBAL shard count — ``hash_shuffle`` single-level,
-    ``hash_shuffle_two_level`` two-level), psum'd over the mesh, so the
-    result is the true arrival histogram.  Returns ``(hist, overload)``
-    with ``overload = max_load / fair_share`` (1.0 = balanced).
+    ``hash_shuffle_two_level`` two-level, where destination ``d`` is in pod
+    ``d // n`` at in-pod index ``d % n``, ``n = N / num_pods``), psum'd over
+    the mesh in one collective.  Returns ``(counts, overload)``: ``counts``
+    is ``int32[N + 2]``, the per-destination arrival histogram followed by
+    the rows that cross pods (destination pod other than the sender's) and
+    the rows the in-pod hop moves between chips (in-pod index other than
+    the sender's: the cross-pod hop keeps it); ``overload = max_load /
+    fair_share`` (1.0 = balanced).
     """
+    n = num_shards // num_pods
     dest = (
         core_exchange.fibonacci_hash(keys.astype(jnp.int32))
         % jnp.uint32(num_shards)
@@ -192,10 +198,16 @@ def _shuffle_histogram(
     local = jnp.zeros((num_shards,), jnp.int32).at[dest].add(
         valid.astype(jnp.int32)
     )
-    hist = lax.psum(local, axes)
+    me = _global_shard_index(num_shards, num_pods)
+    moved = jnp.stack([
+        jnp.sum(valid & (dest // n != me // n), dtype=jnp.int32),
+        jnp.sum(valid & (dest % n != me % n), dtype=jnp.int32),
+    ])
+    counts = lax.psum(jnp.concatenate([local, moved]), axes)
+    hist = counts[:num_shards]
     total = jnp.maximum(hist.sum(), 1).astype(jnp.float32)
     overload = hist.max().astype(jnp.float32) * num_shards / total
-    return hist, overload
+    return counts, overload
 
 
 def _global_shard_index(num_shards: int, num_pods: int) -> jax.Array:
@@ -217,17 +229,19 @@ def _route_and_report(
     per-row from the row index so one key spreads evenly); below it —
     stats were wrong, data is balanced — the exchange stays a plain hash
     and downstream partial+combine still reduces correctly.  Returns the
-    routing-key override (None = plain) and the report entry exposed as
-    ``run.exchange_report``.
+    routing-key override (None = plain) and the edge's report entry: the
+    chosen route's ``counts`` (:func:`_shuffle_histogram`), both overloads
+    and the salting decision, read back as the run's
+    :class:`~repro.obs.trace.ExchangeEdge`.
     """
     info = node.info
     keys = tbl[info["key"]].astype(jnp.int32)
-    hist_plain, over_plain = _shuffle_histogram(
-        keys, tbl.valid, num_shards, axes
+    counts_plain, over_plain = _shuffle_histogram(
+        keys, tbl.valid, num_shards, num_pods, axes
     )
     if not info.get("salted"):
         return None, {
-            "hist": hist_plain,
+            "counts": counts_plain,
             "overload": over_plain,
             "plain_overload": over_plain,
             "salted": jnp.bool_(False),
@@ -249,9 +263,11 @@ def _route_and_report(
     route = jnp.where(
         do_salt & jnp.isin(keys, heavy) & tbl.valid, salted_keys, keys
     )
-    hist, overload = _shuffle_histogram(route, tbl.valid, num_shards, axes)
+    counts, overload = _shuffle_histogram(
+        route, tbl.valid, num_shards, num_pods, axes
+    )
     return route, {
-        "hist": hist,
+        "counts": counts,
         "overload": overload,
         "plain_overload": over_plain,
         "salted": do_salt,

@@ -63,6 +63,9 @@ class Expr:
     def f32(self) -> "Expr":
         return Cast(self, "f32")
 
+    def i32(self) -> "Expr":
+        return Cast(self, "i32")
+
     # -- operator overloads (non-Expr operands become literals) -------------
     def __add__(self, o):
         return Bin("+", self, _wrap(o))
@@ -84,6 +87,9 @@ class Expr:
 
     def __truediv__(self, o):
         return Bin("/", self, _wrap(o))
+
+    def __floordiv__(self, o):
+        return Bin("//", self, _wrap(o))
 
     def __lt__(self, o):
         return Bin("<", self, _wrap(o))
@@ -149,6 +155,7 @@ _OPS = {
     "-": lambda a, b: a - b,
     "*": lambda a, b: a * b,
     "/": lambda a, b: a / b,
+    "//": lambda a, b: a // b,
     "<": lambda a, b: a < b,
     "<=": lambda a, b: a <= b,
     ">": lambda a, b: a > b,

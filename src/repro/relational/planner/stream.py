@@ -365,8 +365,10 @@ def compile_plan_streamed(
             msg_cap = min(cap, int(ctx.exchange_rows))
         rows = jnp.stack([t[c].astype(jnp.int32) for c in columns], axis=1)
         keys = t[n.info["key"]].astype(jnp.int32)
-        hist, _over = _shuffle_histogram(keys, t.valid, num_shards, axes)
-        reports[report_keys[id(n)]] = hist
+        counts, _over = _shuffle_histogram(
+            keys, t.valid, num_shards, num_pods, axes
+        )
+        reports[report_keys[id(n)]] = counts
         if do_spill:
             out_rows, out_valid, spilled = mux.hash_shuffle_spill(
                 keys, rows, SHUFFLE_AXIS, capacity=msg_cap, valid=t.valid
@@ -727,7 +729,7 @@ def compile_plan_streamed(
     _mark_streaming(plan.root, set())
 
     def _accumulate_reports(edge_hists, reports, p: int) -> None:
-        """Fold one step's psum'd histograms into the per-(edge, pass)
+        """Fold one step's psum'd arrival counts into the per-(edge, pass)
         accumulators.  Keyed by pass: a shuffle shared across passes (Q17's
         lineitem shuffle feeds both) re-ships the stream per pass, so each
         traversal is measured against the model separately — summing them
@@ -750,10 +752,10 @@ def compile_plan_streamed(
         out: dict = {}
         for (k, p), (h, n_steps) in sorted(edge_hists.items()):
             key = f"{k}@p{p}" if len(passes_of[k]) > 1 else k
-            total = max(int(h.sum()), 1)
-            over = float(h.max()) * num_shards / total
+            hist = h[:num_shards]
+            over = float(hist.max()) * num_shards / max(int(hist.sum()), 1)
             out[key] = {
-                "hist": h,
+                "counts": h,
                 "traversals": 1 if k in streaming_edge_keys else n_steps,
                 "overload": over,
                 "plain_overload": over,

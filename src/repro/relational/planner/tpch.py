@@ -264,6 +264,15 @@ def q6(year: int = 1994) -> PlannedQuery:
 # Q17: small-quantity-order revenue — the paper's Fig 6 worked example.
 # One broadcast (filtered part), ONE lineitem shuffle shared by the
 # correlated-AVG group-by and the join back.
+#
+# ``l_quantity < 0.2 * avg(l_quantity)`` is decided in integers: a float32
+# average flips exact ties (a TPU divides by multiplying with the
+# reciprocal).  For an integer quantity q, ``5 * q * cnt < sum_qty`` holds
+# exactly when ``q < ceil(sum_qty / (5 * cnt))``, so each part's group row
+# carries that ceiling, the smallest quantity that is not small, and the
+# join back gathers this one int32 column, as it gathered the average
+# before.  The group sums accumulate in float32, exact for integer totals
+# below 2**24 (a part has about 30 lineitems of quantity at most 50).
 # ----------------------------------------------------------------------------
 
 def q17(brand: int = 12, container: int = 2) -> PlannedQuery:
@@ -284,22 +293,20 @@ def q17(brand: int = 12, container: int = 2) -> PlannedQuery:
             ("cnt", lit(1), "count"),
         ),
     )
-    avg = Project(
+    # a padding row of the group table has no lineitems: keep its divisor 1
+    five_cnt = lit(5) * where(col("cnt") < lit(1), lit(1), col("cnt").i32())
+    limit = Project(
         g,
         keep=("l_partkey",),
         derived=(
-            (
-                "avg_qty",
-                col("sum_qty")
-                / where(col("cnt") < lit(1), lit(1.0), col("cnt").f32()),
-            ),
+            ("qty_limit", (col("sum_qty").i32() + five_cnt - lit(1)) // five_cnt),
         ),
     )
     back = HashJoin(
-        build=avg, probe=semi, build_key="l_partkey", probe_key="l_partkey",
-        payload=("avg_qty",),
+        build=limit, probe=semi, build_key="l_partkey", probe_key="l_partkey",
+        payload=("qty_limit",),
     )
-    small = Filter(back, col("l_quantity").f32() < lit(0.2) * col("avg_qty"))
+    small = Filter(back, col("l_quantity") < col("qty_limit"))
     agg = Aggregate(small, (("revenue", col("l_extendedprice").f32(), "sum"),))
     return PlannedQuery(
         "q17", ("lineitem", "part"), agg,
