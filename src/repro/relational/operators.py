@@ -7,20 +7,21 @@ group-by aggregation, PK-FK join, top-k.
 
 HARDWARE ADAPTATION (DESIGN.md §2): HyPer's joins/aggregations are
 hash-table-based — pointer chasing that x86 cores love and TPU vector units
-hate.  The TPU-idiomatic equivalents used here are *sort-based*: bitonic
-sort + ``searchsorted`` for PK-FK joins and sorted segment reduction for
-group-by.  Same results, same asymptotics up to the log factor, but contiguous
-vector memory traffic instead of random probes.  (The paper itself cites
-MPSM [2] — sort-merge — as the NUMA-friendly choice; the same argument holds
-one level down on the TPU.)  The *distributed* layer on top (queries.py) is
-exactly the paper's: partition/broadcast decisions + the scheduled exchange.
+hate.  The TPU-idiomatic equivalents used here are *sort-based*: one sort
+of build and probe keys together for PK-FK joins and sorted segment
+reduction for group-by.  Same results, same asymptotics up to the log
+factor, but contiguous vector memory traffic instead of random probes.
+(The paper itself cites MPSM [2] — sort-merge — as the NUMA-friendly
+choice; the same argument holds one level down on the TPU.)  The
+*distributed* layer on top (queries.py) is exactly the paper's:
+partition/broadcast decisions + the scheduled exchange.
 """
 
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .table import Table
 
@@ -182,17 +183,54 @@ def join_pk(
 ) -> tuple[jax.Array, jax.Array]:
     """Sorted PK-FK join: returns (build_row_index, match_mask) per probe row.
 
-    Build side is sorted once (invalid keys to +inf), probes binary-search it.
     ``build_row_index`` addresses the ORIGINAL build table order, so callers
-    gather payload columns directly.
+    gather payload columns directly; every index is in range, and where
+    ``match`` is False it means nothing.  On duplicate valid build keys a
+    probe takes the lowest build row.
+
+    Build and probe rows are sorted together by (key, rank) (scope
+    ``probe_merge``); each probe is handed the head of its run of equal keys
+    by a fill-forward scan, and a second sort returns them to probe order:
+    no random gather, no loop.  The rank puts, on equal keys, the valid
+    build rows first (lowest row first), then the probes, then the invalid
+    build rows, which so never head a run that holds a probe: rank
+    ``r < nb`` is build row ``r``, ``nb + j`` is probe row ``j``,
+    ``nb + npr`` an invalid build row.  A probe matches iff its run's head
+    is a valid build row.
     """
-    skeys = jnp.where(build_valid, build_keys.astype(jnp.int32), _KEY_SENTINEL)
-    order = jnp.argsort(skeys)
-    sk = skeys[order]
-    pos = jnp.searchsorted(sk, probe_keys.astype(jnp.int32))
-    pos = jnp.clip(pos, 0, sk.shape[0] - 1)
-    match = (sk[pos] == probe_keys.astype(jnp.int32)) & probe_valid
-    return order[pos], match
+    nb, npr = build_keys.shape[0], probe_keys.shape[0]
+    if nb + npr > _KEY_SENTINEL:
+        raise ValueError(f"{nb} + {npr} rows overflow the int32 rank")
+    with jax.named_scope("probe_merge"):
+        keys = jnp.concatenate(
+            [build_keys.astype(jnp.int32), probe_keys.astype(jnp.int32)]
+        )
+        rank = jnp.concatenate([
+            jnp.where(build_valid, jnp.arange(nb, dtype=jnp.int32), nb + npr),
+            nb + jnp.arange(npr, dtype=jnp.int32),
+        ])
+        keys, rank = lax.sort((keys, rank), num_keys=2, is_stable=False)
+        head = jnp.concatenate([jnp.ones((1,), jnp.bool_), keys[1:] != keys[:-1]])
+        found = _fill_forward(jnp.where(head, jnp.where(rank < nb, rank, -1), _EMPTY))
+        is_probe = (rank >= nb) & (rank < nb + npr)
+        back = jnp.where(is_probe, rank - nb, npr)  # other rows sort past the end
+        _, found = lax.sort((back, jnp.where(is_probe, found, -1)), is_stable=False)
+        found = found[:npr]
+        return jnp.maximum(found, 0), (found >= 0) & probe_valid
+
+
+_EMPTY = -2  # a slot the fill-forward scan writes over
+
+
+def _fill_forward(x: jax.Array) -> jax.Array:
+    """Each ``_EMPTY`` slot takes the nearest value before it that is not
+    ``_EMPTY``: ceil(log2(n)) shifted vector passes (Hillis-Steele)."""
+    shift = 1
+    while shift < x.shape[0]:
+        prev = jnp.pad(x[:-shift], (shift, 0), constant_values=_EMPTY)
+        x = jnp.where(x == _EMPTY, prev, x)
+        shift *= 2
+    return x
 
 
 def gather_payload(

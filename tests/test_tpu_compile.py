@@ -10,6 +10,7 @@ read back without one.
 
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,11 +69,12 @@ def test_partition_pack_compiles(one_chip, num_partitions):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_q3_executor_compiles_on_four_chips(topo, monkeypatch):
-    """A planned Q3 at SF 4 with the Pallas pack, on a 4-way mesh of the
-    described chips.  The executor builds its mesh from ``jax.devices()``,
-    places real tables and asks the backend whether to interpret kernels;
-    the test steers those three to the described chips and to shapes."""
+def _compile_sf4(topo, monkeypatch, pq, num_pods: int = 1):
+    """A planned template at SF 4 with the Pallas pack, on the described
+    2x2 as a flat 4-way mesh or a ``(pod, q)`` mesh.  The executor builds
+    its mesh from ``jax.devices()``, places real tables and asks the
+    backend whether to interpret kernels; this steers those three to the
+    described chips and to shapes.  Returns the compiled program."""
     from repro.kernels import ops
     from repro.relational import datagen
     from repro.relational.context import ExecutionContext
@@ -81,7 +83,11 @@ def test_q3_executor_compiles_on_four_chips(topo, monkeypatch):
     from repro.relational.table import Table
 
     n = 4
-    mesh = Mesh(np.array(topo.devices[:n]), (ex.SHUFFLE_AXIS,))
+    devices = np.array(topo.devices[:n])
+    if num_pods > 1:
+        mesh = Mesh(devices.reshape(num_pods, -1), ("pod", ex.SHUFFLE_AXIS))
+    else:
+        mesh = Mesh(devices, (ex.SHUFFLE_AXIS,))
 
     def place(table, num_shards, mesh, axes):
         cap = math.ceil(table.capacity / num_shards) * num_shards
@@ -96,7 +102,6 @@ def test_q3_executor_compiles_on_four_chips(topo, monkeypatch):
     monkeypatch.setattr(ex, "_place", place)
     monkeypatch.setattr(ops, "_interpret", lambda: False)
 
-    pq = tpch.q3()
     catalog = tpch.tpch_catalog(4)
     schema = datagen.gen_all(0.001)  # column names and dtypes only
     tables = {
@@ -109,17 +114,49 @@ def test_q3_executor_compiles_on_four_chips(topo, monkeypatch):
         )
         for name in pq.tables
     }
-    run = ex.compile_plan(
-        pq.plan(catalog, n), tables, ExecutionContext(num_shards=n, pack_impl="pallas")
-    )
-    compiled = run._jfn.lower(*run._flat).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    ctx = ExecutionContext(num_shards=n, num_pods=num_pods, pack_impl="pallas")
+    run = ex.compile_plan(pq.plan(catalog, n, num_pods=num_pods), tables, ctx)
+    return run._jfn.lower(*run._flat).compile()
+
+
+def _device_bytes(compiled) -> int:
     mem = compiled.memory_analysis()
-    per_device = (
+    return (
         mem.argument_size_in_bytes + mem.output_size_in_bytes
         + mem.temp_size_in_bytes - mem.alias_size_in_bytes
     )
-    assert 0 < per_device < HBM_BYTES, per_device
+
+
+def test_q3_executor_compiles_on_four_chips(topo, monkeypatch):
+    from repro.relational.planner import tpch
+
+    compiled = _compile_sf4(topo, monkeypatch, tpch.q3())
+    assert "tpu_custom_call" in compiled.as_text()
+    assert 0 < _device_bytes(compiled) < HBM_BYTES, _device_bytes(compiled)
+
+
+def test_q17_pod_program_fits_a_chip(topo, monkeypatch):
+    """Q17 at SF 4 on the ``(pod, q)`` mesh: its joins probe 24M-row
+    post-shuffle tables by the merge (no search loop), whose co-sorts are
+    the program's largest operands, and the program still fits one chip's
+    memory."""
+    from repro.relational.planner import tpch
+
+    compiled = _compile_sf4(topo, monkeypatch, tpch.q17(), num_pods=2)
+    assert not re.search(r'while\(.*op_name="[^"]*/join_pk/', compiled.as_text())
+    assert 0 < _device_bytes(compiled) < HBM_BYTES, _device_bytes(compiled)
+
+
+@pytest.mark.parametrize("num_pods", [1, 2], ids=["flat", "pod"])
+def test_q18_program_fits_a_chip(topo, monkeypatch, num_pods):
+    """Q18 at SF 4 on the flat 4-way mesh and on the ``(pod, q)`` mesh: its
+    two joins merge (no search loop) and the program fits one chip's
+    memory."""
+    from repro.relational.planner import tpch
+
+    compiled = _compile_sf4(topo, monkeypatch, tpch.q18(), num_pods=num_pods)
+    assert not re.search(r'while\(.*op_name="[^"]*/join_pk/', compiled.as_text())
+    assert 0 < _device_bytes(compiled) < HBM_BYTES, _device_bytes(compiled)
 
 
 # SF 1 lineitem on one chip, and the columns Q1 reads.
@@ -162,3 +199,26 @@ def test_dense_groupby_above_compare_limit_scatters(one_chip):
         )
     ).lower(gid, valid).compile()
     assert _has_scatter(compiled)
+
+
+# Q3's join of SF 1 orders into lineitem on one chip.
+ORDERS_SF1 = 1_500_000
+
+
+def _join_pk_hlo(one_chip, num_build: int, num_probe: int) -> str:
+    from repro.relational import operators as ops
+
+    def shape(n, dtype):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    return jax.jit(ops.join_pk).lower(
+        shape(num_build, jnp.int32), shape(num_build, jnp.bool_),
+        shape(num_probe, jnp.int32), shape(num_probe, jnp.bool_),
+    ).compile().as_text()
+
+
+@pytest.mark.parametrize("num_probe", [LINEITEM_SF1, 256])
+def test_join_probe_compiles_without_a_loop(one_chip, num_probe):
+    """SF 1 orders into lineitem (Q3), and into a small streamed morsel:
+    the probe is two sorts and a scan, no loop of dependent gather passes."""
+    assert "while(" not in _join_pk_hlo(one_chip, ORDERS_SF1, num_probe)

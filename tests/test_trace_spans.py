@@ -168,3 +168,19 @@ def test_q1_dense_groupby_scope_names_its_path():
         for m in re.findall(r'op_name="(jit\(q1\)/groupby_dense/[^"]*)"', hlo)
     }
     assert "compare" in paths and "scatter" not in paths, paths
+
+
+def test_q3_join_scopes_name_their_probe():
+    """Q3's joins probe by the merge, under ``join_pk/probe_merge``: no op
+    there is a loop of dependent binary-search passes."""
+    tabs = datagen.gen_all(SF)
+    pq = tpch.q3()
+    tables = {t: tabs[t] for t in pq.tables}
+    plan = pq.plan({t: tables[t].capacity for t in pq.tables}, 1)
+    run = compile_plan(plan, tables)
+    hlo = run._jfn.lower(*run._flat).compile().as_text()
+    found = re.findall(r'= .*? ([a-z][\w-]*)\(.*op_name="jit\(q3\)/join_pk/([^/"]*)', hlo)
+    assert {path for _, path in found} == {"probe_merge"}, found
+    ops = {op for op, _ in found}
+    assert "sort" in ops and "while" not in ops, sorted(ops)
+
